@@ -23,6 +23,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+_NT = ((1,), (1,))      # a·bᵀ
+_NN = ((1,), (0,))      # a·b
+_TN = ((0,), (0,))      # aᵀ·b
+
+
+def _precision(dtype):
+    """f32 inputs contract at full f32 precision: Mosaic's default takes
+    one bf16 pass over f32 operands, which misses f32 tolerances on the
+    chip. Narrower inputs keep the default."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
+def _dot(a, b, contract, precision):
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
@@ -50,11 +66,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
 
     @pl.when(jnp.asarray(reachable) & jnp.asarray(in_window))
     def _compute():
+        prec = _precision(q_ref.dtype)
         q = q_ref[0].astype(jnp.float32)               # (bq, d)
         k = k_ref[0].astype(jnp.float32)               # (bk, d)
         v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _dot(q, k, _NT, prec) * scale
         q_pos = q_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         mask = k_pos < kv_len
@@ -70,9 +86,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = alpha * l_ref[...] + jnp.sum(p, -1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * alpha + _dot(p, v, _NN, prec)
         m_ref[...] = m_new
 
     @pl.when(ik == nk - 1)
@@ -81,7 +95,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         l = jnp.where(l == 0.0, 1.0, l)                # fully-masked rows
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
         # logsumexp rows — consumed by the backward kernels
-        lse_ref[0] = (m_ref[...] + jnp.log(l))[:, 0]
+        lse_ref[0] = m_ref[...] + jnp.log(l)
 
 
 def _blocks(S: int, T: int, block_q: int, block_k: int):
@@ -98,7 +112,12 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True, window: int = 0,
                         block_q: int = 128, block_k: int = 128,
                         interpret: bool = False):
-    """→ (out (B,S,Hq,D), lse (B*Hq, S))."""
+    """→ (out (B,S,Hq,D), lse (B*Hq, S, 1)).
+
+    The logsumexp rows keep a trailing unit dim: a ``(1, bq, 1)`` block is
+    one the TPU accepts (second-minor a multiple of 8, minor the whole
+    dim), where a ``(1, bq)`` block of a 2-D array is not.
+    """
     B, S, Hq, D = q.shape
     T, Hkv = k.shape[1], k.shape[2]
     group = Hq // Hkv
@@ -124,11 +143,11 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, D), lambda h, iq, ik: (h, iq, 0)),
-            pl.BlockSpec((1, bq), lambda h, iq, ik: (h, iq)),
+            pl.BlockSpec((1, bq, 1), lambda h, iq, ik: (h, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * Hq, S, D), q.dtype),
-            jax.ShapeDtypeStruct((B * Hq, S), jnp.float32),
+            jax.ShapeDtypeStruct((B * Hq, S, 1), jnp.float32),
         ],
         scratch_shapes=[
             # (bq, 1) running max / sum, (bq, D) f32 accumulator — VMEM
@@ -184,20 +203,16 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(jnp.asarray(reachable) & jnp.asarray(in_window))
     def _compute():
+        prec = _precision(q_ref.dtype)
         q = q_ref[0].astype(jnp.float32)
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _dot(q, k, _NT, prec) * scale
         mask = _mask(s.shape, q_lo, k_lo, causal, window, kv_len)
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0][:, None]), 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, None])
-        acc_ref[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        p = jnp.where(mask, jnp.exp(s - lse_ref[0]), 0.0)
+        ds = p * (_dot(do, v, _NT, prec) - delta_ref[0])
+        acc_ref[...] += _dot(ds, k, _NN, prec) * scale
 
     @pl.when(ik == nk - 1)
     def _done():
@@ -219,21 +234,17 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     # jq walks (group × q-blocks); the q row block is jq % nq_per_head
     q_lo = (jq % nq_per_head) * block_q
 
+    prec = _precision(q_ref.dtype)
     q = q_ref[0].astype(jnp.float32)
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+    s = _dot(q, k, _NT, prec) * scale
     mask = _mask(s.shape, q_lo, k_lo, causal, window, kv_len)
-    p = jnp.where(mask, jnp.exp(s - lse_ref[0][:, None]), 0.0)
-    dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                       preferred_element_type=jnp.float32)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[0][:, None])
-    dk_acc[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                       preferred_element_type=jnp.float32) * scale
+    p = jnp.where(mask, jnp.exp(s - lse_ref[0]), 0.0)
+    dv_acc[...] += _dot(p, do, _TN, prec)
+    ds = p * (_dot(do, v, _NT, prec) - delta_ref[0])
+    dk_acc[...] += _dot(ds, q, _TN, prec) * scale
 
     @pl.when(jq == nq - 1)
     def _done():
@@ -244,7 +255,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=0,
                         block_q: int = 128, block_k: int = 128,
                         interpret: bool = False):
-    """Returns (dq, dk, dv). lse: (B*Hq, S) from the forward."""
+    """Returns (dq, dk, dv). lse: (B*Hq, S, 1) from the forward."""
     B, S, Hq, D = q.shape
     T, Hkv = k.shape[1], k.shape[2]
     group = Hq // Hkv
@@ -258,7 +269,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=0,
     dor = do.transpose(0, 2, 1, 3).reshape(B * Hq, S, D)
     # Δ = rowsum(do ∘ o) — cheap elementwise precompute
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1).transpose(0, 2, 1).reshape(B * Hq, S)
+                    axis=-1).transpose(0, 2, 1).reshape(B * Hq, S, 1)
 
     def kv_index(h, iq, ik):
         b, hq = h // Hq, h % Hq
@@ -273,8 +284,8 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=0,
             pl.BlockSpec((1, bk, D), kv_index),
             pl.BlockSpec((1, bk, D), kv_index),
             pl.BlockSpec((1, bq, D), lambda h, iq, ik: (h, iq, 0)),
-            pl.BlockSpec((1, bq), lambda h, iq, ik: (h, iq)),
-            pl.BlockSpec((1, bq), lambda h, iq, ik: (h, iq)),
+            pl.BlockSpec((1, bq, 1), lambda h, iq, ik: (h, iq, 0)),
+            pl.BlockSpec((1, bq, 1), lambda h, iq, ik: (h, iq, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, D), lambda h, iq, ik: (h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((B * Hq, S, D), q.dtype),
@@ -289,11 +300,6 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=0,
         g, iq = j // nq, j % nq
         return (b * Hq + hkv * group + g, iq, 0)
 
-    def q_row_index(hk, ik, j):
-        b, hkv = hk // Hkv, hk % Hkv
-        g, iq = j // nq, j % nq
-        return (b * Hq + hkv * group + g, iq)
-
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, scale=scale, causal=causal,
                           window=window, block_q=bq, block_k=bk, kv_len=T,
@@ -304,8 +310,8 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=0,
             pl.BlockSpec((1, bk, D), lambda hk, ik, j: (hk, ik, 0)),
             pl.BlockSpec((1, bk, D), lambda hk, ik, j: (hk, ik, 0)),
             pl.BlockSpec((1, bq, D), q_index),
-            pl.BlockSpec((1, bq), q_row_index),
-            pl.BlockSpec((1, bq), q_row_index),
+            pl.BlockSpec((1, bq, 1), q_index),
+            pl.BlockSpec((1, bq, 1), q_index),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, D), lambda hk, ik, j: (hk, ik, 0)),

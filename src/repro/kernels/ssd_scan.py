@@ -31,6 +31,11 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref, *,
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
+    # f32 inputs contract at full f32 precision (Mosaic's default is one
+    # bf16 pass); the prefix sums always do, since exp(segsum) amplifies
+    # their error
+    hi = jax.lax.Precision.HIGHEST
+    prec = hi if x_ref.dtype == jnp.float32 else None
     x = x_ref[0].astype(jnp.float32)          # (Q, P)
     dt = dt_ref[0].astype(jnp.float32)        # (Q, 1) — padded lane dim
     a = a_ref[0].astype(jnp.float32)          # (1, 1)
@@ -38,32 +43,44 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref, *,
     c = c_ref[0].astype(jnp.float32)          # (Q, N)
 
     dA = dt * a                               # (Q, 1), ≤ 0
-    cum = jnp.cumsum(dA, axis=0)              # (Q, 1) inclusive
-    # segsum(i, j) = cum[i] - cum[j]  for i ≥ j (strictly: sum_{j+1..i})
-    seg = cum - cum.reshape(1, chunk)         # (Q, Q) via broadcast
     tri = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
         jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(tri, jnp.exp(seg), 0.0)
+    # inclusive prefix sums as matmuls with the lower-triangular ones
+    # matrix (Mosaic has no cumsum): dAb[t, j] = dA[t], so
+    # cum_col[i, j] = Σ_{t≤i} dA[t] = cum[i] and cum_row[i, j] = cum[j]
+    ones = tri.astype(jnp.float32)
+    dAb = jnp.broadcast_to(dA, (chunk, chunk))
+    cum_col = jax.lax.dot_general(ones, dAb, (((1,), (0,)), ((), ())),
+                                  precision=hi,
+                                  preferred_element_type=jnp.float32)
+    cum_row = jax.lax.dot_general(dAb, ones, (((0,), (1,)), ((), ())),
+                                  precision=hi,
+                                  preferred_element_type=jnp.float32)
+    cum = cum_col[:, :1]                      # (Q, 1) inclusive
+    # segsum(i, j) = cum[i] - cum[j]  for i ≥ j (strictly: sum_{j+1..i})
+    L = jnp.where(tri, jnp.exp(cum_col - cum_row), 0.0)
 
     xdt = x * dt                              # (Q, P)
-    cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
+    cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())), precision=prec,
                              preferred_element_type=jnp.float32)   # (Q, Q)
     y = jax.lax.dot_general(cb * L, xdt, (((1,), (0,)), ((), ())),
+                            precision=prec,
                             preferred_element_type=jnp.float32)    # (Q, P)
 
     # inter-chunk: contribution of the incoming state
     h_in = h_ref[...]                         # (P, N)
     decay_in = jnp.exp(cum)                   # (Q, 1)
     y += decay_in * jax.lax.dot_general(
-        c, h_in, (((1,), (1,)), ((), ())),
+        c, h_in, (((1,), (1,)), ((), ())), precision=prec,
         preferred_element_type=jnp.float32)   # (Q, N)·(P, N)ᵀ → (Q, P)
 
     # state update: h' = h·exp(sum dA) + Σ_s exp(cum[-1]-cum[s]) dt_s x_s B_sᵀ
-    total = cum[chunk - 1]                    # (1,)
-    w = jnp.exp(total.reshape(1, 1) - cum)    # (Q, 1)
+    total = jnp.sum(dA, axis=0, keepdims=True)  # (1, 1)
+    w = jnp.exp(total - cum)                  # (Q, 1)
     hs = jax.lax.dot_general(xdt * w, b, (((0,), (0,)), ((), ())),
+                             precision=prec,
                              preferred_element_type=jnp.float32)   # (P, N)
-    h_ref[...] = h_in * jnp.exp(total).reshape(1, 1) + hs
+    h_ref[...] = h_in * jnp.exp(total) + hs
     y_ref[0] = y.astype(y_ref.dtype)
 
 

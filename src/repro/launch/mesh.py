@@ -2,31 +2,36 @@
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state. The dry-run process sets XLA_FLAGS to fake 512 host devices *before*
-any jax import; everything else sees the real (single-CPU) topology.
+any jax import; everything else sees the real topology.
+
+Every mesh is built here with Auto axis types: the train step places its
+tensors with ``with_sharding_constraint``, which accepts only Auto axes.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Any, Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
+              devices: Optional[Sequence[Any]] = None) -> Mesh:
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh() -> Mesh:
-    """Whatever this host actually has (tests/examples: 1 CPU device)."""
-    n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model")) if n > 1 else \
-        jax.make_mesh((1, 1), ("data", "model"))
+def make_host_mesh(devices: Optional[Sequence[Any]] = None) -> Mesh:
+    """``devices`` (default: every device of this host) on the ``model``
+    axis."""
+    devices = list(devices) if devices is not None else jax.devices()
+    return make_mesh((1, len(devices)), ("data", "model"), devices)
 
 
 def mesh_device_count(mesh: Mesh) -> int:

@@ -9,20 +9,24 @@
 hardware; on CPU use --smoke). The training job is compiled into a workflow
 DAG and scheduled through the CWSI (chunks → eval → checkpoint tasks), so
 restarts, provenance, and runtime prediction all come from the CWS.
+
+The state lives in the step's own shardings on the host mesh, so the same
+code trains on one chip or on every chip of a host.
 """
 from __future__ import annotations
 
 import argparse
 import os
-from dataclasses import replace
+import time
+from pathlib import Path
+from typing import Any, Callable, Dict, Optional
 
 import jax
-import jax.numpy as jnp
-import numpy as np
+from jax.sharding import Mesh
 
 from ..checkpoint import latest_checkpoint, restore_checkpoint, save_checkpoint
 from ..configs import get_config
-from ..configs.base import ShapeConfig, TrainConfig
+from ..configs.base import ModelConfig, ShapeConfig, TrainConfig
 from ..data import DataConfig, TokenPipeline
 from ..models import build_model
 from ..runtime.orchestrator import (
@@ -34,11 +38,115 @@ from ..runtime.orchestrator import (
 from ..runtime.train import init_state, make_train_step
 from .mesh import make_host_mesh
 
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, where set, is read by JAX itself and
+    nothing here overrides it. Otherwise the cache sits at a fixed path in
+    the checkout: the path is part of the cache key, so it must not move.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
 
 def preset_100m(cfg):
     """~100M-param dense config of the same family (full driver target)."""
     return cfg.scaled(n_layers=12, d_model=768, n_heads=12, n_kv_heads=12,
                       d_ff=3072, vocab=32768)
+
+
+def run_training(cfg: ModelConfig, *, steps: int, chunk: int, batch: int,
+                 seq: int, microbatch: int, lr: float, seed: int = 0,
+                 mesh: Optional[Mesh] = None, ckpt_dir: str = "",
+                 ckpt_every: int = 20, strategy: str = "rank_min_rr",
+                 log: Callable[[str], None] = print) -> Dict[str, Any]:
+    """Train ``cfg`` as a chunked workflow run by CWS → ``LocalExecutor``.
+
+    Returns the per-step ``losses`` and ``step_seconds`` (each step timed
+    to ``block_until_ready``), ``compile_seconds``, ``chunk_runs`` (how
+    often each chunk task's body ran, in chunk order), the finished
+    ``dag`` and the final ``state``.
+    """
+    if batch % microbatch:
+        raise ValueError(f"batch {batch} is not a multiple of "
+                         f"microbatch {microbatch}")
+    mesh = mesh if mesh is not None else make_host_mesh()
+    model = build_model(cfg)
+    log(f"[train] arch={cfg.name} params={model.n_params():,} "
+        f"mesh={dict(mesh.shape)}")
+
+    shape = ShapeConfig("driver", seq, batch, "train")
+    tcfg = TrainConfig(learning_rate=lr, warmup_steps=10,
+                       microbatch_per_device=microbatch)
+    step, state_sh, batch_sh, state_specs = make_train_step(
+        model, tcfg, shape, mesh, total_steps=steps)
+    jstep = jax.jit(step, in_shardings=(state_sh, batch_sh),
+                    out_shardings=(state_sh, None), donate_argnums=(0,))
+    pipe = TokenPipeline(DataConfig(vocab=cfg.vocab, seq_len=seq,
+                                    global_batch=batch, seed=seed))
+
+    ck = latest_checkpoint(ckpt_dir) if ckpt_dir else None
+    if ck:
+        state, manifest = restore_checkpoint(ck, state_specs, state_sh)
+        start_step = int(manifest["step"])
+        log(f"[train] resumed from {ck} at step {start_step}")
+    else:
+        # built in place: each device makes only its own shards
+        state = jax.jit(lambda key: init_state(model, tcfg, key,
+                                               total_steps=steps),
+                        out_shardings=state_sh)(jax.random.PRNGKey(seed))
+        start_step = 0
+
+    t0 = time.perf_counter()
+    compiled = jstep.lower(state, jax.device_put(pipe.batch(start_step),
+                                                 batch_sh)).compile()
+    compile_s = time.perf_counter() - t0
+
+    shared = SharedState(state)
+    losses, step_s = [], []
+    chunk_runs: Dict[int, int] = {}
+
+    def run_chunk(sh: SharedState, start: int, stop: int):
+        chunk_runs[start] = chunk_runs.get(start, 0) + 1
+        for s in range(start, stop):
+            b = jax.device_put(pipe.batch(s), batch_sh)
+            t = time.perf_counter()
+            sh.state, m = compiled(sh.state, b)
+            jax.block_until_ready((sh.state, m))
+            step_s.append(time.perf_counter() - t)
+            losses.append(float(m["loss"]))
+        log(f"[train] step {stop:5d} loss {losses[-1]:.4f} "
+            f"lr {float(m['lr']):.2e} gnorm {float(m['grad_norm']):.2f}")
+        return {"step": stop, "loss": losses[-1]}
+
+    def run_ckpt(sh: SharedState, step_no: int):
+        save_checkpoint(ckpt_dir, step_no, sh.state, {"arch": cfg.name})
+        log(f"[train] checkpoint @ {step_no}")
+
+    spec = TrainJobSpec(job_id=f"train-{cfg.name}",
+                        n_steps=steps - start_step, chunk=chunk,
+                        ckpt_every=ckpt_every if ckpt_dir else 0)
+    dag = build_training_workflow(
+        spec, lambda sh, a, b: run_chunk(sh, a + start_step, b + start_step),
+        shared,
+        run_ckpt=(lambda sh, s: run_ckpt(sh, s + start_step))
+        if ckpt_dir else None)
+    rt = LocalRuntime(n_nodes=1, strategy=strategy)
+    try:
+        rt.run(dag, timeout_s=6000)
+    finally:
+        rt.shutdown()
+    return {"losses": losses, "step_seconds": step_s,
+            "compile_seconds": compile_s,
+            "chunk_runs": [chunk_runs.get(a, 0) for a in
+                           range(start_step, steps, chunk)],
+            "dag": dag, "state": shared.state}
 
 
 def main() -> None:
@@ -49,6 +157,9 @@ def main() -> None:
     ap.add_argument("--steps", type=int, default=60)
     ap.add_argument("--chunk", type=int, default=10)
     ap.add_argument("--batch", type=int, default=8)
+    ap.add_argument("--microbatch", type=int, default=None,
+                    help="rows per gradient-accumulation micro-step "
+                         "(default: the whole batch in one)")
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=3e-3)
     ap.add_argument("--ckpt-dir", default="")
@@ -57,69 +168,19 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.preset == "100m":
         cfg = preset_100m(cfg)
-    model = build_model(cfg)
-    print(f"[train] arch={cfg.name} params={model.n_params():,}")
-
-    shape = ShapeConfig("driver", args.seq, args.batch, "train")
-    tcfg = TrainConfig(learning_rate=args.lr, warmup_steps=10,
-                       microbatch_per_device=args.batch)
-    mesh = make_host_mesh()
-    step, _, _, _ = make_train_step(model, tcfg, shape, mesh,
-                                    total_steps=args.steps)
-    jstep = jax.jit(step, donate_argnums=(0,))
-    pipe = TokenPipeline(DataConfig(vocab=cfg.vocab, seq_len=args.seq,
-                                    global_batch=args.batch, seed=args.seed))
-
-    state = init_state(model, tcfg, jax.random.PRNGKey(args.seed),
-                       total_steps=args.steps)
-    start_step = 0
-    if args.ckpt_dir:
-        ck = latest_checkpoint(args.ckpt_dir)
-        if ck:
-            state, manifest = restore_checkpoint(ck, state)
-            start_step = int(manifest["step"])
-            print(f"[train] resumed from {ck} at step {start_step}")
-
-    shared = SharedState(state)
-
-    def run_chunk(sh: SharedState, start: int, stop: int):
-        loss = float("nan")
-        for s in range(start, stop):
-            batch = {k: jnp.asarray(v) for k, v in pipe.batch(s).items()}
-            sh.state, m = jstep(sh.state, batch)
-            loss = float(m["loss"])
-        print(f"[train] step {stop:5d} loss {loss:.4f} "
-              f"lr {float(m['lr']):.2e} gnorm {float(m['grad_norm']):.2f}")
-        return {"step": stop, "loss": loss}
-
-    def run_ckpt(sh: SharedState, step_no: int):
-        save_checkpoint(args.ckpt_dir, step_no, sh.state,
-                        {"arch": cfg.name})
-        print(f"[train] checkpoint @ {step_no}")
-
-    spec = TrainJobSpec(job_id=f"train-{cfg.name}",
-                        n_steps=args.steps - start_step,
-                        chunk=args.chunk,
-                        ckpt_every=args.ckpt_every if args.ckpt_dir else 0)
-
-    def chunk_with_offset(sh, a, b):
-        return run_chunk(sh, a + start_step, b + start_step)
-
-    def ckpt_with_offset(sh, s):
-        return run_ckpt(sh, s + start_step)
-
-    dag = build_training_workflow(
-        spec, chunk_with_offset, shared,
-        run_ckpt=ckpt_with_offset if args.ckpt_dir else None)
-    rt = LocalRuntime(n_nodes=1, strategy=args.strategy)
-    rt.run(dag, timeout_s=6000)
-    losses = [m["loss"] for m in shared.metrics if "loss" in m]
-    print(f"[train] done: first-chunk loss {losses[0]:.3f} → "
-          f"last-chunk loss {losses[-1]:.3f}")
-    rt.shutdown()
+    out = run_training(cfg, steps=args.steps, chunk=args.chunk,
+                       batch=args.batch, seq=args.seq,
+                       microbatch=args.microbatch or args.batch, lr=args.lr,
+                       seed=args.seed, ckpt_dir=args.ckpt_dir,
+                       ckpt_every=args.ckpt_every, strategy=args.strategy)
+    losses = out["losses"]
+    if losses:
+        print(f"[train] done: first-step loss {losses[0]:.3f} → "
+              f"last-step loss {losses[-1]:.3f}")
 
 
 if __name__ == "__main__":

@@ -54,10 +54,7 @@ def make_train_step(model: Model, tcfg: TrainConfig, shape: ShapeConfig,
                     mesh: Mesh, multi_pod: bool = False,
                     total_steps: int = 10_000):
     """Returns (train_step, state_shardings, batch_shardings, state_specs)."""
-    opt = AdamW(lr=warmup_cosine(tcfg.learning_rate, tcfg.warmup_steps,
-                                 total_steps),
-                weight_decay=tcfg.weight_decay, grad_clip=tcfg.grad_clip,
-                mom_dtype=tcfg.opt_dtype)
+    opt = _optimizer(tcfg, total_steps)
     n_micro = n_microbatches(shape, mesh, tcfg, multi_pod)
     rules = train_rules(multi_pod, model.cfg.family)
 
@@ -177,12 +174,18 @@ def _zero1_shardings(p_specs: Any, p_axes: Any, rules: Rules, mesh: Mesh,
     return jax.tree.unflatten(treedef, out)
 
 
+def _optimizer(tcfg: TrainConfig, total_steps: int) -> AdamW:
+    return AdamW(lr=warmup_cosine(tcfg.learning_rate, tcfg.warmup_steps,
+                                  total_steps),
+                 weight_decay=tcfg.weight_decay, grad_clip=tcfg.grad_clip,
+                 mom_dtype=tcfg.opt_dtype)
+
+
 def init_state(model: Model, tcfg: TrainConfig, rng: jax.Array,
                total_steps: int = 10_000) -> Dict[str, Any]:
-    """Unsharded state init for tests/examples on the host mesh."""
-    opt = AdamW(lr=warmup_cosine(tcfg.learning_rate, tcfg.warmup_steps,
-                                 total_steps),
-                weight_decay=tcfg.weight_decay, grad_clip=tcfg.grad_clip)
+    """Unsharded state init on the default device; its dtypes match what
+    the train step returns, so a compiled step takes its own output."""
+    opt = _optimizer(tcfg, total_steps)
     params = model.init(rng)
     return {"params": params, "opt": opt.init(params),
             "data_step": jnp.zeros((), jnp.int32)}
