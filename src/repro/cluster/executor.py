@@ -8,9 +8,17 @@ time feeding the provenance store and the online predictors.
 Each registered "node" is a worker lane with cpu/memory bookkeeping — on a
 real deployment these lanes map to TPU slices; here they map to host threads
 (the container has a single core, so lanes mostly pipeline I/O-free work).
+
+The hand-off is spanned for the profiler (``jax.profiler.TraceAnnotation``):
+``executor.launch``, ``executor.start``, ``task.body`` and
+``executor.finish`` carry the ``task`` id, ``executor.lock`` times each
+engine-lock acquisition (``where`` = start, finish or poll), and
+``cws.round`` each scheduling round (``forced`` = 1 for the poll's). With no
+profiler session a span records nothing.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 import traceback
@@ -38,12 +46,24 @@ class LocalExecutor:
         self._launches: Dict[str, int] = {}
         self.cws: Optional[CommonWorkflowScheduler] = None
         self.outputs: Dict[str, Any] = {}
+        # imported here: the simulator's users import this module too
+        from jax.profiler import TraceAnnotation
+        self._span = TraceAnnotation
+        self._forcing = False           # the poll's forced round is running
 
     def now(self) -> float:
         return time.monotonic() - self._t0
 
     def attach(self, cws: CommonWorkflowScheduler) -> None:
         self.cws = cws
+        # span each round at the engine's instance-level ``schedule`` seam,
+        # so spans and ``op_counts()["rounds"]`` agree one for one
+        base = cws.schedule
+
+        def schedule(now: float) -> int:
+            with self._span("cws.round", forced=int(self._forcing)):
+                return base(now)
+        cws.schedule = schedule
         with self._lock:
             # commands through the apply seam, same as the simulator: a
             # journaled engine records this executor's history verbatim
@@ -61,7 +81,8 @@ class LocalExecutor:
         # capture the launch id now: the Task object is shared, so a
         # relaunch would otherwise make a stale worker report under the
         # live launch's id
-        self._pool.submit(self._run, task, node, task.launch_id)
+        with self._span("executor.launch", task=task.task_id):
+            self._pool.submit(self._run, task, node, task.launch_id)
 
     def kill(self, task_id: str) -> None:
         # cooperative: the worker's result is discarded. A preempted
@@ -73,16 +94,27 @@ class LocalExecutor:
         if task_id in self._launches:
             self._cancelled[task_id] = True
 
+    @contextlib.contextmanager
+    def _locked(self, where: str):
+        """Hold the engine lock; its acquisition is spanned."""
+        with self._span("executor.lock", where=where):
+            self._lock.acquire()
+        try:
+            yield
+        finally:
+            self._lock.release()
+
     def _run(self, task: Task, node: str, launch_id: int) -> None:
         assert self.cws is not None
-        with self._lock:
-            self.cws.apply(_cmd.TaskStarted(task.task_id,
-                                            launch_id=launch_id),
+        tid = task.task_id
+        with self._span("executor.start", task=tid), self._locked("start"):
+            self.cws.apply(_cmd.TaskStarted(tid, launch_id=launch_id),
                            self.now())
         t0 = time.monotonic()
         try:
             fn = task.spec.fn
-            out = fn(**task.spec.params.get("kwargs", {})) if fn else None
+            with self._span("task.body", task=tid):
+                out = fn(**task.spec.params.get("kwargs", {})) if fn else None
             ok, reason = True, ""
         except Exception as e:  # noqa: BLE001 — task failure is data here
             out, ok, reason = None, False, f"{type(e).__name__}: {e}"
@@ -91,7 +123,8 @@ class LocalExecutor:
         peak = 0
         if isinstance(out, dict) and "peak_mem_bytes" in out:
             peak = int(out["peak_mem_bytes"])
-        with self._lock:
+        with self._span("executor.finish", task=tid), \
+                self._locked("finish"):
             cancelled = self._cancelled.get(task.task_id)
             if self._launches.get(task.task_id) == launch_id:
                 # this worker owns the live launch: retire the cancel
@@ -124,10 +157,15 @@ class LocalExecutor:
             self.cws.apply(_cmd.SubmitWorkflow(dag), self.now())
         deadline = time.monotonic() + timeout_s
         while True:
-            with self._lock:
+            with self._locked("poll"):
                 if dag.finished():
                     break
-                self.cws.apply(_cmd.ScheduleBarrier(force=True), self.now())
+                self._forcing = True
+                try:
+                    self.cws.apply(_cmd.ScheduleBarrier(force=True),
+                                   self.now())
+                finally:
+                    self._forcing = False
             if time.monotonic() > deadline:
                 raise TimeoutError(f"workflow {dag.workflow_id} timed out")
             time.sleep(poll_s)

@@ -22,6 +22,7 @@ from pathlib import Path
 from typing import Any, Callable, Dict, Optional
 
 import jax
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh
 
 from ..checkpoint import latest_checkpoint, restore_checkpoint, save_checkpoint
@@ -69,9 +70,14 @@ def run_training(cfg: ModelConfig, *, steps: int, chunk: int, batch: int,
     """Train ``cfg`` as a chunked workflow run by CWS → ``LocalExecutor``.
 
     Returns the per-step ``losses`` and ``step_seconds`` (each step timed
-    to ``block_until_ready``), ``compile_seconds``, ``chunk_runs`` (how
-    often each chunk task's body ran, in chunk order), the finished
-    ``dag`` and the final ``state``.
+    to ``block_until_ready``), ``compile_seconds``, the ``compiled`` step,
+    ``chunk_runs`` (how often each chunk task's body ran, in chunk order),
+    the finished ``dag`` and the final ``state``.
+
+    Each step's host work is spanned for the profiler (``train.batch``,
+    ``train.put``, ``train.step``, ``train.read``, and ``train.log`` once a
+    chunk, each with its ``step``); with no profiler session a span
+    records nothing.
     """
     if batch % microbatch:
         raise ValueError(f"batch {batch} is not a multiple of "
@@ -115,14 +121,20 @@ def run_training(cfg: ModelConfig, *, steps: int, chunk: int, batch: int,
     def run_chunk(sh: SharedState, start: int, stop: int):
         chunk_runs[start] = chunk_runs.get(start, 0) + 1
         for s in range(start, stop):
-            b = jax.device_put(pipe.batch(s), batch_sh)
-            t = time.perf_counter()
-            sh.state, m = compiled(sh.state, b)
-            jax.block_until_ready((sh.state, m))
-            step_s.append(time.perf_counter() - t)
-            losses.append(float(m["loss"]))
-        log(f"[train] step {stop:5d} loss {losses[-1]:.4f} "
-            f"lr {float(m['lr']):.2e} gnorm {float(m['grad_norm']):.2f}")
+            with TraceAnnotation("train.batch", step=s):
+                hb = pipe.batch(s)
+            with TraceAnnotation("train.put", step=s):
+                b = jax.device_put(hb, batch_sh)
+            with TraceAnnotation("train.step", step=s):
+                t = time.perf_counter()
+                sh.state, m = compiled(sh.state, b)
+                jax.block_until_ready((sh.state, m))
+                step_s.append(time.perf_counter() - t)
+            with TraceAnnotation("train.read", step=s):
+                losses.append(float(m["loss"]))
+        with TraceAnnotation("train.log", step=stop - 1):
+            log(f"[train] step {stop:5d} loss {losses[-1]:.4f} "
+                f"lr {float(m['lr']):.2e} gnorm {float(m['grad_norm']):.2f}")
         return {"step": stop, "loss": losses[-1]}
 
     def run_ckpt(sh: SharedState, step_no: int):
@@ -143,7 +155,7 @@ def run_training(cfg: ModelConfig, *, steps: int, chunk: int, batch: int,
     finally:
         rt.shutdown()
     return {"losses": losses, "step_seconds": step_s,
-            "compile_seconds": compile_s,
+            "compile_seconds": compile_s, "compiled": compiled,
             "chunk_runs": [chunk_runs.get(a, 0) for a in
                            range(start_step, steps, chunk)],
             "dag": dag, "state": shared.state}

@@ -148,13 +148,14 @@ def mamba_block(x: jax.Array, p: Dict[str, jax.Array], cfg: ModelConfig,
     chunk = min(s.chunk, S)
     while S % chunk:
         chunk //= 2
-    if use_pallas:
-        from ..kernels import ops as kops
-        y, _ = kops.ssd_scan(xh, dt.astype(x.dtype), a.astype(x.dtype),
-                             B_, C_, chunk=chunk)
-    else:
-        y, _ = ssd_chunked(xh, dt.astype(x.dtype), a.astype(x.dtype),
-                           B_, C_, chunk=chunk)
+    with jax.named_scope("ssd"):
+        if use_pallas:
+            from ..kernels import ops as kops
+            y, _ = kops.ssd_scan(xh, dt.astype(x.dtype), a.astype(x.dtype),
+                                 B_, C_, chunk=chunk)
+        else:
+            y, _ = ssd_chunked(xh, dt.astype(x.dtype), a.astype(x.dtype),
+                               B_, C_, chunk=chunk)
     y = y + xh * p["d_skip"].astype(x.dtype)[None, None, :, None]
     y = y.reshape(Bb, S, d_in)
     y = rmsnorm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)

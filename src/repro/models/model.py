@@ -83,11 +83,12 @@ class Model:
     def loss(self, params: Dict[str, Any], batch: Dict[str, jax.Array],
              remat: str = "block") -> Tuple[jax.Array, Dict[str, jax.Array]]:
         logits, aux = self.logits(params, batch, remat)
-        lg = logits.astype(jnp.float32)
-        labels = batch["labels"]
-        lse = jax.nn.logsumexp(lg, axis=-1)
-        gold = jnp.take_along_axis(lg, labels[..., None], axis=-1)[..., 0]
-        ce = (lse - gold).mean()
+        with jax.named_scope("head_loss"):
+            lg = logits.astype(jnp.float32)
+            labels = batch["labels"]
+            lse = jax.nn.logsumexp(lg, axis=-1)
+            gold = jnp.take_along_axis(lg, labels[..., None], axis=-1)[..., 0]
+            ce = (lse - gold).mean()
         total = ce
         if self.cfg.moe is not None:
             total = total + self.cfg.moe.aux_loss_weight * aux

@@ -113,42 +113,52 @@ def lm_schema(cfg: ModelConfig) -> Schema:
 def _block(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
            positions: jax.Array, kind: str,
            use_pallas: bool = False) -> Tuple[jax.Array, jax.Array]:
+    # named scopes label the step's device ops by layer in the HLO metadata
+    # (and so in a profile); they leave the computation as it is
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    q, k, v = qkv_project(h, p["attn"], cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_)
-    q = apply_rope(q, positions, fraction=cfg.rope_fraction, theta=cfg.rope_theta)
-    k = apply_rope(k, positions, fraction=cfg.rope_fraction, theta=cfg.rope_theta)
+    with jax.named_scope("attn_proj"):
+        q, k, v = qkv_project(h, p["attn"], cfg.n_heads, cfg.n_kv_heads,
+                              cfg.head_dim_)
+        q = apply_rope(q, positions, fraction=cfg.rope_fraction,
+                       theta=cfg.rope_theta)
+        k = apply_rope(k, positions, fraction=cfg.rope_fraction,
+                       theta=cfg.rope_theta)
     win = _window_of(cfg, kind)
-    if use_pallas:
-        from ..kernels import ops as kops
-        attn = kops.flash_attention(q, k, v, causal=True, window=win)
-    else:
-        attn = attention(q, k, v, causal=True, window=win)
+    with jax.named_scope("attn_core"):
+        if use_pallas:
+            from ..kernels import ops as kops
+            attn = kops.flash_attention(q, k, v, causal=True, window=win)
+        else:
+            attn = attention(q, k, v, causal=True, window=win)
     B, S = x.shape[:2]
-    x = x + jnp.einsum("bsh,hd->bsd", attn.reshape(B, S, -1), p["attn"]["wo"])
+    with jax.named_scope("attn_proj"):
+        x = x + jnp.einsum("bsh,hd->bsd", attn.reshape(B, S, -1),
+                           p["attn"]["wo"])
 
     h = rmsnorm(x, p["ln2"], cfg.norm_eps)
-    if cfg.family == "moe":
-        # nested remat: during the layer backward, re-dispatch instead of
-        # holding E×C×ff expert intermediates + cotangents simultaneously
-        y, aux = jax.checkpoint(
-            lambda hh, pp: moe_ffn(hh, pp, cfg.moe))(h, p["ffn"])
-    else:
-        y = swiglu(h, p["ffn"]["w_gate"], p["ffn"]["w_up"], p["ffn"]["w_down"])
-        aux = jnp.zeros((), jnp.float32)
+    with jax.named_scope("mlp"):
+        if cfg.family == "moe":
+            # nested remat: during the layer backward, re-dispatch instead
+            # of holding E×C×ff expert intermediates + cotangents at once
+            y, aux = jax.checkpoint(
+                lambda hh, pp: moe_ffn(hh, pp, cfg.moe))(h, p["ffn"])
+        else:
+            y = swiglu(h, p["ffn"]["w_gate"], p["ffn"]["w_up"],
+                       p["ffn"]["w_down"])
+            aux = jnp.zeros((), jnp.float32)
     return x + y, aux
 
 
 def embed_inputs(cfg: ModelConfig, params: Dict[str, Any],
                  tokens: jax.Array,
                  patches: Optional[jax.Array] = None) -> jax.Array:
-    x = params["embed"]["table"][tokens]
-    if cfg.family in ("dense", "vlm", "moe"):
-        pass
-    if patches is not None and cfg.vision is not None:
-        pe = jnp.einsum("bpc,cd->bpd", patches.astype(x.dtype),
-                        params["vision_proj"])
-        x = jnp.concatenate([pe, x], axis=1)
-    return x
+    with jax.named_scope("embed"):
+        x = params["embed"]["table"][tokens]
+        if patches is not None and cfg.vision is not None:
+            pe = jnp.einsum("bpc,cd->bpd", patches.astype(x.dtype),
+                            params["vision_proj"])
+            x = jnp.concatenate([pe, x], axis=1)
+        return x
 
 
 def forward(cfg: ModelConfig, params: Dict[str, Any], tokens: jax.Array,
@@ -183,9 +193,10 @@ def forward(cfg: ModelConfig, params: Dict[str, Any], tokens: jax.Array,
 
 
 def unembed(cfg: ModelConfig, params: Dict[str, Any], x: jax.Array) -> jax.Array:
-    if cfg.tie_embeddings:
-        return jnp.einsum("bsd,vd->bsv", x, params["embed"]["table"])
-    return jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
+    with jax.named_scope("head_loss"):
+        if cfg.tie_embeddings:
+            return jnp.einsum("bsd,vd->bsv", x, params["embed"]["table"])
+        return jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
 
 
 # ---------------------------------------------------------------------------

@@ -134,8 +134,9 @@ def make_train_step(model: Model, tcfg: TrainConfig, shape: ShapeConfig,
             micro_step, (zeros, jnp.zeros((), jnp.float32)),
             micro_batches(batch))
 
-        new_params, new_opt, opt_metrics = opt.update(grads, state["opt"],
-                                                      params)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, opt_metrics = opt.update(
+                grads, state["opt"], params)
         out_metrics = {
             "loss": loss,
             "ce": metrics["ce"].mean(),
