@@ -192,13 +192,14 @@ def phase_kernels(sm: Smoke, seed: int) -> None:
     params = jax.jit(build_model(cfg).init)(jax.random.PRNGKey(seed))
     batch = jax.device_put(TokenPipeline(DataConfig(
         vocab=cfg.vocab, seq_len=1024, global_batch=2, seed=seed)).batch(0))
+    # both paths forced: the train step would pick the kernel on a TPU
     losses = {}
     for use_pallas in (True, False):
         loss_fn = build_model(cfg, use_pallas=use_pallas).loss
         compiled = jax.jit(loss_fn).lower(params, batch).compile()
-        if use_pallas:
-            sm.check("kernels", "tpu_custom_call in the use_pallas=True "
-                     "program", "tpu_custom_call" in compiled.as_text())
+        sm.check("kernels", ("" if use_pallas else "no ") + "tpu_custom_call "
+                 f"in the use_pallas={use_pallas} program",
+                 ("tpu_custom_call" in compiled.as_text()) == use_pallas)
         losses[use_pallas] = float(compiled(params, batch)[0])
     rel = abs(losses[True] - losses[False]) / abs(losses[False])
     sm.check("kernels", f"{ARCH} full-width loss, Pallas vs XLA",
@@ -217,6 +218,9 @@ def _train(sm: Smoke, tag: str, devices, seed: int):
     out = run_training(cfg, seed=seed, mesh=mesh,
                        log=lambda s: print(f"[{tag}] {s}", flush=True),
                        **TRAIN)
+    want = "pallas" if mesh.devices.size == 1 else "xla"
+    sm.check(tag, f"the step runs {want} attention", out["attention"] == want,
+             out["attention"])
     chunks = [t for t in out["dag"].tasks.values() if t.name == "train_chunk"]
     n_chunks = -(-TRAIN["steps"] // TRAIN["chunk"])
     once = (len(chunks) == n_chunks == len(out["chunk_runs"])

@@ -212,4 +212,3 @@ class RunConfig:
     shape: ShapeConfig
     train: TrainConfig = field(default_factory=TrainConfig)
     multi_pod: bool = False
-    use_pallas: bool = False         # TPU only; CPU dry-run uses XLA ref path

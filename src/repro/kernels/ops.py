@@ -3,7 +3,8 @@
 On CPU (this container) the kernels execute in interpret mode — Python
 evaluation of the kernel body, used by the test suite to validate against
 the ``ref.py`` oracles. On TPU backends they compile natively. The model
-code calls these through ``use_pallas=True``.
+code calls these where its ``use_pallas`` is set or, for attention, where
+the train step selects the kernel (``runtime/train.py::attention_path``).
 """
 from __future__ import annotations
 
@@ -28,30 +29,34 @@ def _interpret() -> bool:
 
 
 # differentiable flash attention: Pallas forward + Pallas flash-v2 backward
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q, k, v, causal: bool, window: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, causal: bool, window: int, interpret: bool):
     return flash_attention_fwd(q, k, v, causal=causal, window=window,
-                               interpret=_interpret())[0]
+                               interpret=interpret)[0]
 
 
-def _flash_fwd(q, k, v, causal, window):
+def _flash_fwd(q, k, v, causal, window, interpret):
     o, lse = flash_attention_fwd(q, k, v, causal=causal, window=window,
-                                 interpret=_interpret())
+                                 interpret=interpret)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, window, res, do):
+def _flash_bwd(causal, window, interpret, res, do):
     q, k, v, o, lse = res
     return flash_attention_bwd(q, k, v, o, lse, do, causal=causal,
-                               window=window, interpret=_interpret())
+                               window=window, interpret=interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window"))
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0):
-    return _flash(q, k, v, causal, window)
+@functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
+def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
+                    interpret: Optional[bool] = None):
+    """``interpret`` None: interpret mode unless the default backend is a
+    TPU. A step compiled for a TPU from another host passes False."""
+    return _flash(q, k, v, causal, window,
+                  _interpret() if interpret is None else interpret)
 
 
 @jax.jit

@@ -36,7 +36,7 @@ from ..runtime.orchestrator import (
     TrainJobSpec,
     build_training_workflow,
 )
-from ..runtime.train import init_state, make_train_step
+from ..runtime.train import attention_path, init_state, make_train_step
 from .mesh import make_host_mesh
 
 REPO_ROOT = Path(__file__).resolve().parents[3]
@@ -71,6 +71,8 @@ def run_training(cfg: ModelConfig, *, steps: int, chunk: int, batch: int,
 
     Returns the per-step ``losses`` and ``step_seconds`` (each step timed
     to ``block_until_ready``), ``compile_seconds``, the ``compiled`` step,
+    the ``attention`` it runs (``"pallas"`` or ``"xla"``, as
+    ``attention_path`` picks it and the first log line says),
     ``chunk_runs`` (how often each chunk task's body ran, in chunk order),
     the finished ``dag`` and the final ``state``.
 
@@ -84,10 +86,11 @@ def run_training(cfg: ModelConfig, *, steps: int, chunk: int, batch: int,
                          f"microbatch {microbatch}")
     mesh = mesh if mesh is not None else make_host_mesh()
     model = build_model(cfg)
-    log(f"[train] arch={cfg.name} params={model.n_params():,} "
-        f"mesh={dict(mesh.shape)}")
 
     shape = ShapeConfig("driver", seq, batch, "train")
+    attention = attention_path(model, mesh, shape)
+    log(f"[train] arch={cfg.name} params={model.n_params():,} "
+        f"mesh={dict(mesh.shape)} attention={attention}")
     tcfg = TrainConfig(learning_rate=lr, warmup_steps=10,
                        microbatch_per_device=microbatch)
     step, state_sh, batch_sh, state_specs = make_train_step(
@@ -156,6 +159,7 @@ def run_training(cfg: ModelConfig, *, steps: int, chunk: int, batch: int,
         rt.shutdown()
     return {"losses": losses, "step_seconds": step_s,
             "compile_seconds": compile_s, "compiled": compiled,
+            "attention": attention,
             "chunk_runs": [chunk_runs.get(a, 0) for a in
                            range(start_step, steps, chunk)],
             "dag": dag, "state": shared.state}

@@ -30,9 +30,17 @@ _DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
 
 
 class Model:
-    def __init__(self, cfg: ModelConfig, use_pallas: bool = False) -> None:
+    """``use_pallas``: True or False forces the Pallas kernels on or off;
+    None leaves causal attention to the train step, which picks it by the
+    devices of its mesh (``runtime/train.py::attention_path``), and
+    elsewhere means off. ``interpret`` runs them in interpret mode (None:
+    unless the default backend is a TPU)."""
+
+    def __init__(self, cfg: ModelConfig, use_pallas: Optional[bool] = None,
+                 interpret: Optional[bool] = None) -> None:
         self.cfg = cfg
         self.use_pallas = use_pallas
+        self.interpret = interpret
         self.param_dtype = _DTYPES[cfg.param_dtype]
         if cfg.family in ("dense", "vlm", "moe"):
             self.schema: Schema = transformer.lm_schema(cfg)
@@ -62,19 +70,18 @@ class Model:
     def logits(self, params: Dict[str, Any], batch: Dict[str, jax.Array],
                remat: str = "block") -> Tuple[jax.Array, jax.Array]:
         cfg = self.cfg
-        if cfg.family in ("dense", "moe"):
+        use_pallas = bool(self.use_pallas)
+        if cfg.family in ("dense", "moe", "vlm"):
             return transformer.forward(cfg, params, batch["tokens"],
-                                       remat=remat, use_pallas=self.use_pallas)
-        if cfg.family == "vlm":
-            return transformer.forward(cfg, params, batch["tokens"],
-                                       patches=batch["patches"], remat=remat,
-                                       use_pallas=self.use_pallas)
+                                       patches=batch.get("patches"),
+                                       remat=remat, use_pallas=use_pallas,
+                                       interpret=self.interpret)
         if cfg.family == "ssm":
             return mamba2.ssm_forward(cfg, params, batch["tokens"],
-                                      remat=remat, use_pallas=self.use_pallas)
+                                      remat=remat, use_pallas=use_pallas)
         if cfg.family == "hybrid":
             return hybrid.forward(cfg, params, batch["tokens"], remat=remat,
-                                  use_pallas=self.use_pallas)
+                                  use_pallas=use_pallas)
         if cfg.family == "audio":
             return encdec.forward(cfg, params, batch["tokens"],
                                   batch["frames"], remat=remat)
@@ -195,5 +202,5 @@ class Model:
         return 6.0 * self.cfg.active_param_count()
 
 
-def build_model(cfg: ModelConfig, use_pallas: bool = False) -> Model:
+def build_model(cfg: ModelConfig, use_pallas: Optional[bool] = None) -> Model:
     return Model(cfg, use_pallas)

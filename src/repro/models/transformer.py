@@ -111,8 +111,8 @@ def lm_schema(cfg: ModelConfig) -> Schema:
 # forward (train / prefill): full-sequence causal
 # ---------------------------------------------------------------------------
 def _block(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
-           positions: jax.Array, kind: str,
-           use_pallas: bool = False) -> Tuple[jax.Array, jax.Array]:
+           positions: jax.Array, kind: str, use_pallas: bool = False,
+           interpret: Optional[bool] = None) -> Tuple[jax.Array, jax.Array]:
     # named scopes label the step's device ops by layer in the HLO metadata
     # (and so in a profile); they leave the computation as it is
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
@@ -127,7 +127,8 @@ def _block(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
     with jax.named_scope("attn_core"):
         if use_pallas:
             from ..kernels import ops as kops
-            attn = kops.flash_attention(q, k, v, causal=True, window=win)
+            attn = kops.flash_attention(q, k, v, causal=True, window=win,
+                                        interpret=interpret)
         else:
             attn = attention(q, k, v, causal=True, window=win)
     B, S = x.shape[:2]
@@ -163,8 +164,12 @@ def embed_inputs(cfg: ModelConfig, params: Dict[str, Any],
 
 def forward(cfg: ModelConfig, params: Dict[str, Any], tokens: jax.Array,
             patches: Optional[jax.Array] = None, remat: str = "block",
-            use_pallas: bool = False) -> Tuple[jax.Array, jax.Array]:
-    """→ (logits over the *token* positions, aux_loss)."""
+            use_pallas: bool = False, interpret: Optional[bool] = None,
+            ) -> Tuple[jax.Array, jax.Array]:
+    """→ (logits over the *token* positions, aux_loss).
+
+    ``use_pallas`` runs causal attention as the flash kernel, in interpret
+    mode where ``interpret`` says (None: unless the backend is a TPU)."""
     x = embed_inputs(cfg, params, tokens, patches)
     B, S, _ = x.shape
     positions = jnp.arange(S)[None, :]
@@ -175,7 +180,8 @@ def forward(cfg: ModelConfig, params: Dict[str, Any], tokens: jax.Array,
         h = maybe_seq_shard(h)
         for i, kind in enumerate(pat):
             pi = jax.tree.map(lambda a: a[i], gp)
-            h, a = _block(cfg, pi, h, positions, kind, use_pallas)
+            h, a = _block(cfg, pi, h, positions, kind, use_pallas,
+                          interpret)
             aux = aux + a
         return (maybe_seq_shard(h), aux), None
 

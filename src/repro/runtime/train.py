@@ -50,10 +50,42 @@ def n_microbatches(shape: ShapeConfig, mesh: Mesh, tcfg: TrainConfig,
 
 
 # ---------------------------------------------------------------------------
+def attention_path(model: Model, mesh: Mesh, shape: ShapeConfig) -> str:
+    """The causal attention a train step of ``model`` on ``mesh`` runs:
+    ``"pallas"`` (the flash kernel) or ``"xla"``.
+
+    An explicit ``model.use_pallas`` decides. Otherwise the kernel runs
+    where it is known to pay: a mesh of one TPU device and a sequence
+    (patches included) that is a multiple of 128. A mesh of several
+    devices keeps XLA until a ``shard_map`` wraps the kernel; so do the
+    families that call ``attention`` directly (hybrid, audio) and those
+    without it.
+    """
+    cfg = model.cfg
+    if cfg.family not in ("dense", "moe", "vlm"):
+        return "xla"
+    if model.use_pallas is not None:
+        return "pallas" if model.use_pallas else "xla"
+    seq = shape.seq_len + (cfg.vision.n_patches if cfg.family == "vlm"
+                           else 0)
+    one_tpu = mesh.devices.size == 1 and mesh.devices.flat[0].platform == "tpu"
+    return "pallas" if one_tpu and seq % 128 == 0 else "xla"
+
+
 def make_train_step(model: Model, tcfg: TrainConfig, shape: ShapeConfig,
                     mesh: Mesh, multi_pod: bool = False,
                     total_steps: int = 10_000):
-    """Returns (train_step, state_shardings, batch_shardings, state_specs)."""
+    """Returns (train_step, state_shardings, batch_shardings, state_specs).
+
+    Attention is chosen here, by ``attention_path``; the SSD kernel runs
+    only where ``model.use_pallas`` asks for it, until mamba2's bf16
+    cumsum is fixed and its cell measures the kernel. Kernels compile
+    natively for a TPU mesh, in interpret mode for any other.
+    """
+    model = Model(model.cfg,
+                  attention_path(model, mesh, shape) == "pallas"
+                  or bool(model.use_pallas),
+                  interpret=mesh.devices.flat[0].platform != "tpu")
     opt = _optimizer(tcfg, total_steps)
     n_micro = n_microbatches(shape, mesh, tcfg, multi_pod)
     rules = train_rules(multi_pod, model.cfg.family)

@@ -5,7 +5,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref
-from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.flash_attention import (
+    flash_attention_bwd,
+    flash_attention_fwd,
+    flash_attention_pallas,
+)
 from repro.kernels.moe_gmm import moe_gmm_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
@@ -29,23 +33,97 @@ def test_rmsnorm(shape, dtype):
                                np.asarray(want, np.float32), **_tol(dtype))
 
 
-@pytest.mark.parametrize("S,T,Hq,Hkv,D,causal,window", [
-    (128, 128, 4, 4, 64, True, 0),      # MHA causal
-    (128, 128, 8, 2, 64, True, 0),      # GQA 4:1
-    (256, 256, 4, 1, 32, True, 64),     # MQA + sliding window
-    (64, 192, 4, 2, 64, False, 0),      # cross-length, bidirectional
-    (96, 96, 2, 2, 128, True, 32),      # non-pow2 seq, window
+def _case(*args, tiles=None, dtype=None, id=None):
+    """A flash case; the first cases keep their ids from before ``tiles``
+    (and, for the backward, ``dtype``) were parameters."""
+    extra = () if dtype is None else (dtype,)
+    return pytest.param(*args, *extra, tiles,
+                        id=id or "-".join(map(str, args)))
+
+
+# tiles: (block_q, block_k, block_h) — None takes the shape rule (_tiles)
+@pytest.mark.parametrize("S,T,Hq,Hkv,D,causal,window,tiles", [
+    _case(128, 128, 4, 4, 64, True, 0, tiles=(64, 64, None)),   # MHA causal
+    _case(128, 128, 8, 2, 64, True, 0, tiles=(64, 64, None)),   # GQA 4:1
+    _case(256, 256, 4, 1, 32, True, 64, tiles=(64, 64, None)),  # MQA + window
+    _case(64, 192, 4, 2, 64, False, 0, tiles=(64, 64, None)),   # cross, bidir
+    _case(96, 96, 2, 2, 128, True, 32, tiles=(64, 64, None)),   # non-pow2, win
+    # several heads a block, blocks above 128, bq != bk
+    _case(512, 512, 8, 8, 64, True, 0, tiles=(256, 128, 4),
+          id="512-512-8-8-64-True-0-q256k128h4"),
+    # the shape rule: S a multiple of 128 but not of the 512 block
+    _case(640, 640, 4, 4, 64, True, 0, id="640-640-4-4-64-True-0-auto"),
+    # GQA, two kv heads of four query heads a block, bk > bq
+    _case(256, 256, 8, 2, 32, True, 0, tiles=(128, 256, 2),
+          id="256-256-8-2-32-True-0-q128k256h2"),
+    # a window that cuts blocks, q and kv blocks of their own sizes
+    _case(512, 512, 4, 2, 32, True, 100, tiles=(128, 256, None),
+          id="512-512-4-2-32-True-100-q128k256"),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention(S, T, Hq, Hkv, D, causal, window, dtype):
+def test_flash_attention(S, T, Hq, Hkv, D, causal, window, tiles, dtype):
     q = jnp.asarray(RNG.normal(0, 1, (2, S, Hq, D)), dtype)
     k = jnp.asarray(RNG.normal(0, 1, (2, T, Hkv, D)), dtype)
     v = jnp.asarray(RNG.normal(0, 1, (2, T, Hkv, D)), dtype)
+    bq, bk, bh = tiles or (None, None, None)
     got = flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                 block_q=64, block_k=64, interpret=True)
+                                 block_q=bq, block_k=bk, block_h=bh,
+                                 interpret=True)
     want = ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
+
+
+def test_flash_attention_kv_len():
+    """Keys from ``kv_len`` on are masked: the same as attending to the
+    first ``kv_len`` keys alone."""
+    q = jnp.asarray(RNG.normal(0, 1, (2, 128, 4, 32)), jnp.float32)
+    k = jnp.asarray(RNG.normal(0, 1, (2, 384, 2, 32)), jnp.float32)
+    v = jnp.asarray(RNG.normal(0, 1, (2, 384, 2, 32)), jnp.float32)
+    got = flash_attention_pallas(q, k, v, causal=False, kv_len=200,
+                                 block_k=128, interpret=True)
+    want = ref.flash_attention_ref(q, k[:, :200], v[:, :200], causal=False)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               **_tol(jnp.float32))
+
+
+@pytest.mark.parametrize("S,T,bq,bk,causal,window,kv_len", [
+    (512, 512, 128, 128, True, 0, None),
+    (512, 512, 256, 128, True, 0, None),
+    (512, 512, 128, 256, True, 100, None),
+    (384, 384, 128, 128, True, 129, None),
+    (256, 512, 128, 128, False, 0, 300),
+    (512, 512, 512, 512, True, 0, None),
+])
+def test_flash_block_spans_are_the_reachable_blocks(S, T, bq, bk, causal,
+                                                    window, kv_len):
+    """The kv blocks each q block visits (forward, dq) and the q blocks
+    each kv block visits (dk/dv) are exactly the pairs that hold an
+    unmasked position, and ``_interior`` holds just where none is masked:
+    the three kernels skip the same pairs."""
+    from repro.kernels import flash_attention as fa
+    q_pos = np.arange(S)[:, None]
+    k_pos = np.arange(T)[None, :]
+    keep = np.ones((S, T), bool)
+    if causal:
+        keep &= k_pos <= q_pos
+    if window:
+        keep &= k_pos > q_pos - window
+    if kv_len is not None:
+        keep &= k_pos < kv_len
+    nq, nk = S // bq, T // bk
+    for iq in range(nq):
+        for ik in range(nk):
+            blk = keep[iq * bq:(iq + 1) * bq, ik * bk:(ik + 1) * bk]
+            lo, hi = fa._kv_span(iq, bq, bk, nk, causal, window, kv_len)
+            assert (int(lo) <= ik <= int(hi)) == blk.any(), (iq, ik)
+            qlo, qhi = fa._q_span(ik, bq, bk, nq, causal, window)
+            beyond = kv_len is not None and ik * bk >= kv_len
+            assert (int(qlo) <= iq <= int(qhi) and not beyond) == blk.any()
+            inner = fa._interior(iq * bq, ik * bk, bq, bk, causal, window,
+                                 kv_len)
+            if blk.any():
+                assert bool(inner) == blk.all(), (iq, ik)
 
 
 @pytest.mark.parametrize("E,C,D,F", [(2, 64, 128, 96), (8, 128, 64, 256),
@@ -111,29 +189,66 @@ def test_attention_q_chunking_equivalence():
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("S,T,Hq,Hkv,D,causal,window", [
-    (128, 128, 4, 2, 32, True, 0),
-    (128, 128, 4, 4, 64, True, 48),
-    (64, 192, 4, 1, 32, False, 0),
+@pytest.mark.parametrize("S,T,Hq,Hkv,D,causal,window,dtype,tiles", [
+    _case(128, 128, 4, 2, 32, True, 0, dtype=jnp.float32),
+    _case(128, 128, 4, 4, 64, True, 48, dtype=jnp.float32),
+    _case(64, 192, 4, 1, 32, False, 0, dtype=jnp.float32),
+    # the new tiling: several heads, bq != bk, causal skips in dk/dv
+    _case(512, 512, 4, 4, 64, True, 0, dtype=jnp.float32,
+          tiles=(256, 128, 2), id="512-512-4-4-64-True-0-q256k128h2"),
+    _case(256, 256, 8, 2, 32, True, 80, dtype=jnp.float32,
+          tiles=(128, 256, None), id="256-256-8-2-32-True-80-q128k256"),
+    # bf16 in, as the models feed it
+    _case(128, 128, 4, 2, 32, True, 0, dtype=jnp.bfloat16,
+          id="128-128-4-2-32-True-0-bf16"),
+    _case(512, 512, 8, 8, 64, True, 0, dtype=jnp.bfloat16,
+          id="512-512-8-8-64-True-0-bf16"),
+    _case(256, 256, 8, 2, 32, True, 80, dtype=jnp.bfloat16,
+          tiles=(128, 256, None), id="256-256-8-2-32-True-80-q128k256-bf16"),
 ])
-def test_flash_attention_backward(S, T, Hq, Hkv, D, causal, window):
+def test_flash_attention_backward(S, T, Hq, Hkv, D, causal, window, dtype,
+                                  tiles):
     """Pallas flash-v2 backward (dq/dk/dv) vs jax.grad of the oracle."""
-    import jax
-    from repro.kernels import ops
-    q = jnp.asarray(RNG.normal(0, 1, (2, S, Hq, D)), jnp.float32)
-    k = jnp.asarray(RNG.normal(0, 1, (2, T, Hkv, D)), jnp.float32)
-    v = jnp.asarray(RNG.normal(0, 1, (2, T, Hkv, D)), jnp.float32)
+    q = jnp.asarray(RNG.normal(0, 1, (2, S, Hq, D)), dtype)
+    k = jnp.asarray(RNG.normal(0, 1, (2, T, Hkv, D)), dtype)
+    v = jnp.asarray(RNG.normal(0, 1, (2, T, Hkv, D)), dtype)
+    do = jnp.asarray(RNG.normal(0, 1, (2, S, Hq, D)), dtype)
+    if tiles is None:
+        _, pullback = jax.vjp(lambda q, k, v: ops.flash_attention(
+            q, k, v, causal=causal, window=window), q, k, v)
+    else:
+        o, lse = flash_attention_fwd(q, k, v, causal=causal, window=window,
+                                     block_q=tiles[0], block_k=tiles[1],
+                                     block_h=tiles[2], interpret=True)
+        pullback = lambda do: flash_attention_bwd(  # noqa: E731
+            q, k, v, o, lse, do, causal=causal, window=window,
+            block_q=tiles[0], block_k=tiles[1], block_h=tiles[2],
+            interpret=True)
+    _, ref_pullback = jax.vjp(lambda q, k, v: ref.flash_attention_ref(
+        q, k, v, causal=causal, window=window), q, k, v)
+    tol = dict(rtol=2e-3, atol=2e-3) if dtype == jnp.float32 \
+        else dict(rtol=5e-2, atol=5e-2)
+    for a, b in zip(pullback(do), ref_pullback(do)):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), **tol)
 
-    def loss_kernel(q, k, v):
-        return (ops.flash_attention(q, k, v, causal=causal,
-                                    window=window) ** 2).sum()
 
-    def loss_ref(q, k, v):
-        return (ref.flash_attention_ref(q, k, v, causal=causal,
-                                        window=window) ** 2).sum()
-
-    g1 = jax.grad(loss_kernel, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g1, g2):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-3, atol=2e-3)
+@pytest.mark.parametrize("causal,window", [(True, 0), (True, 130)])
+def test_flash_attention_backward_skips_unreachable_blocks(causal, window):
+    """NaN in the queries of the first q block reaches only the kv blocks
+    that block attends to: a dk/dv kernel that fetched and computed (even
+    masked) the pairs it cannot reach, as the one-head 128 × 128 kernel
+    did, carries 0 · NaN into every dk."""
+    S, bq = 512, 128
+    q = np.asarray(RNG.normal(0, 1, (1, S, 2, 32)), np.float32)
+    q[:, :bq] = np.nan
+    q = jnp.asarray(q)
+    k, v, do = (jnp.asarray(RNG.normal(0, 1, (1, S, 2, 32)), jnp.float32)
+                for _ in range(3))
+    kw = dict(causal=causal, window=window, block_q=bq, block_k=bq,
+              interpret=True)
+    o, lse = flash_attention_fwd(q, k, v, **kw)
+    dq, dk, dv = flash_attention_bwd(q, k, v, o, lse, do, **kw)
+    assert np.isnan(np.asarray(dk[:, :bq])).any()     # reached: poisoned
+    for g in (o, dq, dk, dv):
+        assert np.isfinite(np.asarray(g[:, bq:])).all()

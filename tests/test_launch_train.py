@@ -38,7 +38,11 @@ def test_host_mesh_takes_a_device_subset():
 
 
 def test_run_training_runs_each_chunk_once_and_loss_falls():
-    out = run_training(CFG, steps=6, chunk=2, log=lambda s: None, **SMALL)
+    lines = []
+    out = run_training(CFG, steps=6, chunk=2, log=lines.append, **SMALL)
+    # a CPU mesh keeps the XLA attention, and the first line says so
+    assert out["attention"] == "xla"
+    assert lines[0].endswith("attention=xla"), lines[0]
     tasks = _chunk_tasks(out)
     assert len(tasks) == 3
     assert all(t.state.value == "SUCCEEDED" and t.attempt == 0

@@ -70,6 +70,26 @@ def test_smoke_train_step_reduces_loss(arch):
     assert losses[-1] < losses[0], losses
 
 
+@pytest.mark.parametrize("arch", sorted(SMOKE_ARCHS))
+def test_cpu_mesh_train_step_keeps_xla_attention(arch):
+    """On a CPU mesh the train step selects XLA attention and traces no
+    Pallas call, so the CPU loss tests compute what they did before the
+    kernel became the TPU default."""
+    from repro.configs.base import ShapeConfig, TrainConfig
+    from repro.launch.mesh import make_host_mesh
+    from repro.runtime.train import attention_path, make_train_step
+
+    cfg = SMOKE_ARCHS[arch]
+    model = build_model(cfg)
+    mesh = make_host_mesh()
+    shape = ShapeConfig("tiny", S, B, "train")
+    assert attention_path(model, mesh, shape) == "xla"
+    step, _, _, specs = make_train_step(
+        model, TrainConfig(microbatch_per_device=B), shape, mesh)
+    jaxpr = str(jax.make_jaxpr(step)(specs, model.input_specs(shape)))
+    assert "pallas_call" not in jaxpr
+
+
 FAMILY_REPRESENTATIVE = {
     "dense": "gemma3-12b",          # exercises local:global + ring buffers
     "moe": "mixtral-8x22b",         # SWA + experts

@@ -69,7 +69,7 @@ def test_flash_attention_bwd_compiles(arch, dtype, one_chip):
     B, S, Hq, Hkv, D = FLASH_WIDTHS[arch]
     q = _sds((B, S, Hq, D), DTYPES[dtype], one_chip)
     kv = _sds((B, S, Hkv, D), DTYPES[dtype], one_chip)
-    lse = _sds((B * Hq, S, 1), jnp.float32, one_chip)
+    lse = _sds((B, Hq, 1, S), jnp.float32, one_chip)
     _compile_kernel(
         lambda q, k, v, o, lse, do: flash_attention_bwd(q, k, v, o, lse, do),
         q, kv, kv, q, lse, q)
@@ -89,10 +89,34 @@ def test_ssd_scan_compiles(dtype, one_chip):
         _sds((B, S, G, N), ty, one_chip))
 
 
+def test_attention_path_follows_the_mesh(topo):
+    """A mesh of one described v5e selects the flash kernel; several
+    devices, a sequence off the 128 grid, or an explicit flag keep XLA."""
+    from repro.configs import get_config
+    from repro.configs.base import ShapeConfig
+    from repro.launch.mesh import make_host_mesh
+    from repro.models import build_model
+    from repro.runtime.train import attention_path
+
+    model = build_model(get_config("qwen1.5-0.5b"))
+    one = make_host_mesh(topo.devices[:1])
+    shape = ShapeConfig("s", 1024, 8, "train")
+    assert attention_path(model, one, shape) == "pallas"
+    assert attention_path(model, make_host_mesh(topo.devices), shape) == "xla"
+    assert attention_path(model, one, ShapeConfig("s", 1000, 8, "train")) \
+        == "xla"
+    forced = build_model(get_config("qwen1.5-0.5b"), use_pallas=False)
+    assert attention_path(forced, one, shape) == "xla"
+    # 32,768 tokens + 576 patches is off the 128 grid
+    vlm = build_model(get_config("phi-3-vision-4.2b"))
+    assert attention_path(vlm, one, ShapeConfig("s", 32768, 1, "train")) \
+        == "xla"
+
+
 def test_full_width_train_step_fits_one_chip(topo):
     """qwen1.5-0.5b at published widths, global batch 8 × 1024 in two
-    micro-steps of 4: arguments + temporaries stay under 15 GiB of the
-    chip's 16."""
+    micro-steps of 4, on the path a one-chip mesh selects (the flash
+    kernel): arguments + temporaries stay under 15 GiB of the chip's 16."""
     from repro.configs import get_config
     from repro.configs.base import ShapeConfig, TrainConfig
     from repro.launch.mesh import make_host_mesh
@@ -108,6 +132,7 @@ def test_full_width_train_step_fits_one_chip(topo):
                        out_shardings=(state_sh, None),
                        donate_argnums=(0,)).lower(
         state_specs, model.input_specs(shape)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < 15 * 2**30, used / 2**30
