@@ -6,34 +6,27 @@ before each query, and a training token costs its forward pass three times
 (forward, and a backward pass of twice its work). Work that remat repeats
 is not counted, nor are elementwise operations, norms and the softmax.
 
-Per-kernel counts (attention, the SSD scan, the head with its loss) are
-here too, with the bytes each must move at least, so that a later kernel
-roofline judges an XLA and a Pallas version on the same work.
+What depends on the model's kind (which weights a token multiplies, which
+sequence mixer it runs) is counted by the configuration's reference module
+(``references/<name>.py``). Per-kernel counts (attention, the SSD scan, the
+head with its loss) are here, with the bytes each must move at least, so
+that a kernel roofline judges an XLA and a Pallas version on the same
+work.
 """
 from __future__ import annotations
 
 from typing import Dict
+
+import reference
 
 TRAIN = 3            # forward + backward (twice the forward)
 
 
 def matmul_params(cfg: Dict) -> int:
     """Weights that enter a matrix product once per token (the head
-    included, the embedding lookup not)."""
-    d, L, V = cfg["hidden_size"], cfg["num_hidden_layers"], cfg["vocab_size"]
-    if cfg["family"] == "dense":
-        H, Hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-        hd, ff = cfg["head_dim"], cfg["intermediate_size"]
-        attn = d * H * hd * 2 + d * Hkv * hd * 2        # q, o; k, v
-        layer = attn + 3 * d * ff                       # SwiGLU
-    elif cfg["family"] == "ssm":
-        d_in = cfg["expand"] * d
-        nh = d_in // cfg["head_dim"]
-        gn = cfg["n_groups"] * cfg["state_size"]
-        layer = d * (2 * d_in + 2 * gn + nh) + d_in * d  # in_proj, out_proj
-    else:
-        raise ValueError(f"no FLOP count for family {cfg['family']!r}")
-    return L * layer + d * V
+    included, the embedding lookup not), as the configuration's reference
+    module counts them."""
+    return reference.load(cfg).matmul_params(cfg)
 
 
 def causal_context(seq: int) -> float:
@@ -60,12 +53,12 @@ def ssd_flops_per_token(cfg: Dict, seq: int) -> float:
 
 
 def train_flops_per_token(cfg: Dict, seq: int) -> float:
-    fwd = 2.0 * matmul_params(cfg)
-    if cfg["family"] == "dense":
-        fwd += attention_flops_per_token(cfg, seq)
-    elif cfg["family"] == "ssm":
-        fwd += ssd_flops_per_token(cfg, seq)
-    return TRAIN * fwd
+    """The weights' matrix products and the configuration's reference
+    module's sequence mixer (attention, the SSD scan...), forward and
+    backward."""
+    kind = reference.load(cfg)
+    return TRAIN * (2.0 * kind.matmul_params(cfg)
+                    + kind.mixer_flops_per_token(cfg, seq))
 
 
 # --- per kernel: (forward FLOPs, least HBM bytes) for one call ---

@@ -8,5 +8,5 @@ def read(rec):
     if not tr or tr["window_s"] <= 0 or not tr["steps"]:
         return None
     flops = rec["flops_per_token"] * tr["steps"] * rec["tokens_per_step"]
-    return 100.0 * flops / tr["window_s"] / (rec["chips"]
-                                              * rec["peak_flops_per_s"])
+    peak = rec["peaks"]["bf16_flops_per_s"]
+    return 100.0 * flops / tr["window_s"] / (rec["chips"] * peak)

@@ -6,7 +6,9 @@
 The cell names a configuration (``configs/<config>.json``) and a traffic mix
 (``traffic/<traffic>.json``); ``cells/<cell>.json`` holds what is the cell's
 own: the step time that sizes the run, the traced steps and the limits of
-the correctness check. Every metric is read by ``metrics/<metric>.py``.
+the correctness check. The configuration names its reference module
+(``references/<name>.py``: the plain model, its FLOP count and its named
+scopes). Every metric is read by ``metrics/<metric>.py``.
 
 The entry the run drives is the program's ``repro.launch.train.run_training``
 as it stands: CWSI → CWS → ``LocalExecutor`` → chunk tasks → the jitted step.
@@ -39,7 +41,6 @@ import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import dataclasses  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import shutil  # noqa: E402
@@ -56,17 +57,14 @@ sys.path.insert(0, str(ROOT / "src"))
 
 import flops  # noqa: E402
 import numpy as np  # noqa: E402
+import reference  # noqa: E402
 import tokens  # noqa: E402
 
 
 def local(name: str):
     """A module of this directory by its file (``trace`` would otherwise
     be the standard library's)."""
-    spec = importlib.util.spec_from_file_location(f"chipbench_{name}",
-                                                  BENCH / f"{name}.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return reference.module_from(BENCH / f"{name}.py", f"chipbench_{name}")
 
 
 # ---------------------------------------------------------------------------
@@ -78,22 +76,25 @@ def _json(path: Path) -> Any:
 
 
 def load_spec(workload: str, root: Path = ROOT) -> Dict[str, Any]:
-    """The cell's entry of ``BENCHMARK.json`` with its configuration,
-    traffic, cell file and the metrics it reports."""
+    """The cell's entry of ``BENCHMARK.json`` with its configuration, its
+    reference module, traffic, cell file and the metrics it reports."""
     bench = _json(root / "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
         raise SystemExit(f"unknown workload {workload!r}; "
                          f"known: {sorted(cells)}")
     w = cells[workload]
-    conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    conf_file = root / {c["name"]: c for c in bench["configs"]}[
+        w["config"]]["file"]
+    conf = _json(conf_file)
 
     def applies(m):
         return workload in m.get("workloads", [workload])
     return {
         "name": workload,
         "chips": w["chips"],
-        "config": _json(root / conf["file"]),
+        "config": conf,
+        "reference": reference.load(conf, source=conf_file),
         "traffic": _json(BENCH / "traffic" / f"{w['traffic']}.json"),
         "cell": _json(BENCH / "cells" / f"{workload}.json"),
         "end_to_end": [m for m in bench["end_to_end"] if applies(m)],
@@ -102,12 +103,8 @@ def load_spec(workload: str, root: Path = ROOT) -> Dict[str, Any]:
 
 
 def reader(metric: str):
-    path = BENCH / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        "metric_" + metric.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return reference.module_from(BENCH / "metrics" / f"{metric}.py",
+                                 "metric_" + metric.replace(".", "_")).read
 
 
 def plan(spec: Dict[str, Any], seconds: float) -> Dict[str, int]:
@@ -196,7 +193,6 @@ class Probe:
         self.fed[step] = batch
         if step not in (1, self.check):
             return
-        import reference
         at = self._state_step()
         if at != step:
             self.problems.append(f"state after {at} steps when the batch of "
@@ -212,7 +208,8 @@ class Probe:
                                 for k, v in smp.items()}
         if step == self.check:
             self.change_norms, self.change_sample = \
-                reference.change_from_seed(self.spec["config"],
+                reference.change_from_seed(self.spec["reference"],
+                                           self.spec["config"],
                                            _flat(opt.master), self.seed)
 
     def readings(self, losses: List[float]) -> Dict[str, Any]:
@@ -239,7 +236,6 @@ class Probe:
 
 def _norms_and_samples(tree):
     import jax
-    import reference
     return jax.jit(lambda t: (reference.leaf_norms(t),
                               reference.leaf_samples(t)))(tree)
 
@@ -358,14 +354,13 @@ def data_mismatch(spec: Dict[str, Any], seed: int, probe: Probe,
 
 def reference_run(spec: Dict[str, Any], seed: int, total_steps: int,
                   **kw) -> Dict[str, Any]:
-    import reference
     cfg, trf = spec["config"], spec["traffic"]
     own = tokens.traffic_for(cfg, trf, seed)
     batches = [own.batch(s) for s in range(spec["cell"]["check_steps"])]
     opt = dict(trf["optimizer"], lr=trf["lr"])
     kw.setdefault("rows", spec["cell"]["ref_rows"])
-    return reference.train_first_steps(cfg, opt, batches, seed, total_steps,
-                                       **kw)
+    return reference.train_first_steps(spec["reference"], cfg, opt, batches,
+                                       seed, total_steps, **kw)
 
 
 def check(spec: Dict[str, Any], seed: int, steps: int, out: Dict[str, Any],
@@ -425,7 +420,9 @@ def chunk_tasks(out: Dict[str, Any]) -> list:
 def window_record(spec, pl, out, probe, t_start, traced: Optional[range],
                   chips: int, device_kind: str) -> Dict[str, Any]:
     """What the metric readers read: the window from the CWS's records of
-    the chunk tasks, and the host clock's offset to the executor's."""
+    the chunk tasks, and the host clock's offset to the executor's; the
+    configuration, the traffic's ``batch`` and ``seq`` and the device's
+    peaks, from which a reader computes a kernel's roofline share."""
     tasks = chunk_tasks(out)
     chunk, w0 = pl["chunk"], pl["prefix_chunks"]
     # the executor stamps a task's start just before its body asks for the
@@ -457,7 +454,10 @@ def window_record(spec, pl, out, probe, t_start, traced: Optional[range],
         "chunk_steps": len(host_chunks) * chunk,
         "flops_per_token": flops.train_flops_per_token(spec["config"],
                                                        trf["seq"]),
-        "peak_flops_per_s": peaks[device_kind]["bf16_flops_per_s"],
+        "peaks": peaks[device_kind],
+        "config": spec["config"],
+        "batch": trf["batch"],
+        "seq": trf["seq"],
         "chips": chips,
         "trace": None,
     }
@@ -491,7 +491,15 @@ def run(spec: Dict[str, Any], seed: int, seconds: float, trace: bool,
     breakdown = None
     if trace:
         files = sorted(Path(trace_dir).rglob("*.xplane.pb"))
-        red = local("trace").reduce_file(str(files[-1])) if files else None
+        red = None
+        if files:
+            tr, sp = local("trace"), local("spans")
+            prof = tr.profile(str(files[-1]))      # parsed once, read twice
+            red = tr.reduce(tr.load(prof))
+            rec["scopes"] = sp.scope_map(
+                out["compiled"].as_text(),
+                sp.SCOPES + spec["reference"].SCOPES)
+            rec["spans"] = sp.reduce(*sp.load(prof), rec["scopes"])
         shutil.rmtree(trace_dir, ignore_errors=True)
         rec["trace"] = red
         if red:

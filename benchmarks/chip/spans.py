@@ -1,6 +1,7 @@
 """Reduction of a profiler trace to the program's own spans and scopes.
 
-    python benchmarks/chip/spans.py <trace.xplane.pb> [<step.hlo.txt>]
+    python benchmarks/chip/spans.py <trace.xplane.pb> [<step.hlo.txt>
+        [<configuration.json>]]
 
 Host spans are the program's ``jax.profiler.TraceAnnotation`` events, on
 the ``/host:`` planes, each with its stats (``step``, ``task``, ``where``,
@@ -11,15 +12,18 @@ A span cut by the trace's start or stop is left out. The reduction gives:
 - ``handoff_s``: from the end of one ``task.body`` to the start of the
   next, for each consecutive pair (lock waits and the thread hop
   included);
-- ``rounds`` and ``periods``: the ``cws.round`` spans that start between
-  the first and the last ``task.body`` start, and the task periods there;
+- ``asked_rounds`` and ``periods``: the ``cws.round`` spans with
+  ``forced`` 0 (the rounds the scheduler's own events ask for, not the
+  driver loop's timed poll) that start between the first and the last
+  ``task.body`` start, and the task periods there;
 - ``idle_s``: the device's idle time in the step window (as ``trace.py``
   takes it) by the innermost program span open on a dispatching thread
   (one that holds ``train.step`` spans) at that instant, ``"(none)"`` where
   none is;
 - ``scope_s``: per execution of the step program, the device self time of
   its operations by the named scope in their HLO ``op_name``
-  (``scope_map``), ``"(unscoped)"`` for the rest, medians over the
+  (``scope_map``: the shared ``SCOPES`` and those of the configuration's
+  reference module), ``"(unscoped)"`` for the rest, medians over the
   executions; ``None`` without a scope map.
 """
 from __future__ import annotations
@@ -32,7 +36,7 @@ import statistics
 import sys
 from collections import defaultdict
 from pathlib import Path
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 _spec = importlib.util.spec_from_file_location(
     "chipbench_trace", Path(__file__).resolve().parent / "trace.py")
@@ -41,8 +45,10 @@ _spec.loader.exec_module(trace)
 
 # the program's spans, by prefix; the harness's own (``bench.``) are not
 PROGRAM = ("train.", "task.", "executor.", "cws.")
+# the named scopes every model's program has; a configuration's reference
+# module adds its own (``SCOPES`` there)
 SCOPES = ("embed", "attn_proj", "attn_core", "mlp", "head_loss",
-          "optimizer", "ssd")
+          "optimizer")
 UNSCOPED, NONE = "(unscoped)", "(none)"
 
 Span = Tuple[str, float, float, dict]     # (name, start_s, end_s, stats)
@@ -51,9 +57,10 @@ _INSTR = re.compile(r'^\s*(?:ROOT\s+)?(%[^\s=]+)\s*=.*?'
                     r'metadata=\{op_name="((?:[^"\\]|\\.)*)"')
 
 
-def scope_map(hlo_text: str) -> Dict[str, str]:
+def scope_map(hlo_text: str,
+              scopes: Sequence[str] = SCOPES) -> Dict[str, str]:
     """Instruction name (``%fusion.12``) → the innermost scope of
-    ``SCOPES`` named in its ``op_name``; unscoped instructions are absent.
+    ``scopes`` named in its ``op_name``; unscoped instructions are absent.
     A scope at the top of a differentiated function shows inside the
     transform's name (``jvp(head_loss)``), so names are split at ``/``,
     ``(`` and ``)``."""
@@ -62,22 +69,21 @@ def scope_map(hlo_text: str) -> Dict[str, str]:
         m = _INSTR.match(line)
         if not m:
             continue
-        hits = [w for w in re.split(r"[/()]", m.group(2)) if w in SCOPES]
+        hits = [w for w in re.split(r"[/()]", m.group(2)) if w in scopes]
         if hits:
             out[m.group(1)] = hits[-1]
     return out
 
 
-def load(path: str) -> Tuple[dict, Optional[Tuple[float, float]]]:
-    """The trace as plain data, and its bounds in seconds.
+def load(pd) -> Tuple[dict, Optional[Tuple[float, float]]]:
+    """A parsed trace (``trace.profile``) as plain data, and its bounds in
+    seconds.
 
     ``{plane: {line: events}}``: on ``/host:`` planes only the program's
     spans, ``(name, start_s, end_s, stats)``, with a line named twice
     (one per thread) keyed ``name#2``, ``name#3``...; on device planes the
     step's lines as ``trace.py`` reads them, ``(name, start_s, end_s)``.
     """
-    from jax.profiler import ProfileData
-    pd = ProfileData.from_file(path)
     planes: Dict[str, Dict[str, list]] = {}
     bounds = None
     for plane in pd.planes:
@@ -203,10 +209,9 @@ def _step_device(planes, scopes: Optional[Dict[str, str]]):
         if i >= 0 and s < runs[i][1]:
             name = text.partition(" = ")[0].strip()
             per_run[i][scopes.get(name, UNSCOPED)] += t
-    keys = set(SCOPES) | {UNSCOPED}
+    keys = {k for r in per_run for k in r}
     return gaps, {k: statistics.median(r.get(k, 0.0) for r in per_run)
-                  for k in sorted(keys)
-                  if any(k in r for r in per_run)}
+                  for k in sorted(keys)}
 
 
 def reduce(planes, bounds=None,
@@ -215,15 +220,16 @@ def reduce(planes, bounds=None,
     spans = sorted((sp for th in threads for sp in th), key=lambda sp: sp[1])
     bodies = [sp for sp in spans if sp[0] == "task.body"]
     handoff = [b[1] - a[2] for a, b in zip(bodies, bodies[1:])]
-    rounds = sum(1 for sp in spans if sp[0] == "cws.round"
-                 and bodies and bodies[0][1] <= sp[1] < bodies[-1][1])
+    asked = sum(1 for sp in spans if sp[0] == "cws.round"
+                and sp[3].get("forced") == 0
+                and bodies and bodies[0][1] <= sp[1] < bodies[-1][1])
     dispatching = [sp for th in threads
                    if any(sp[0] == "train.step" for sp in th) for sp in th]
     gaps, scope_s = _step_device(planes, scopes)
     return {
         "input_s": _per_step(spans, ("train.batch", "train.put")),
         "handoff_s": handoff,
-        "rounds": rounds,
+        "asked_rounds": asked,
         "periods": max(len(bodies) - 1, 0),
         "idle_s": idle_by_span(gaps, dispatching) if gaps is not None
         else None,
@@ -231,12 +237,14 @@ def reduce(planes, bounds=None,
     }
 
 
-def reduce_file(path: str, scopes: Optional[Dict[str, str]] = None) -> dict:
-    planes, bounds = load(path)
-    return reduce(planes, bounds, scopes)
-
-
 if __name__ == "__main__":
-    sc = scope_map(Path(sys.argv[2]).read_text()) if len(sys.argv) > 2 \
-        else None
-    print(json.dumps(reduce_file(sys.argv[1], sc), indent=1))
+    names = SCOPES
+    if len(sys.argv) > 3:
+        sys.path.insert(0, str(Path(__file__).resolve().parent))
+        import reference
+        names += reference.load(json.loads(Path(sys.argv[3]).read_text()),
+                                source=sys.argv[3]).SCOPES
+    sc = scope_map(Path(sys.argv[2]).read_text(), names) \
+        if len(sys.argv) > 2 else None
+    print(json.dumps(reduce(*load(trace.profile(sys.argv[1])), sc),
+                     indent=1))

@@ -58,9 +58,14 @@ def test_rounds_per_task_counts_rounds_between_the_first_and_last_body():
              for k in range(4)]
     r = spans.reduce(_host(bodies + asked, polls))
     assert r["periods"] == 3
-    # [0, 3): 30 polls and 3 asked-for rounds
-    assert r["rounds"] == 33
-    assert R.reader("cws.rounds_per_task")({"spans": r}) == pytest.approx(11)
+    # [0, 3): 30 polls, which follow the task's length and do not count,
+    # and 3 asked-for rounds
+    assert r["asked_rounds"] == 3
+    assert R.reader("cws.rounds_per_task")({"spans": r}) == pytest.approx(1)
+    # a second round asked for at each finish shows, whatever the polls
+    twice = [(n, s + 0.01, e + 0.01, st) for n, s, e, st in asked]
+    r = spans.reduce(_host(bodies + asked + twice, polls))
+    assert R.reader("cws.rounds_per_task")({"spans": r}) == pytest.approx(2)
 
 
 def test_input_time_sums_batch_and_put_of_each_step():
@@ -97,6 +102,24 @@ def test_scope_map_reads_the_innermost_scope_of_each_instruction():
     assert spans.scope_map(HLO) == {"%fusion.1": "head_loss",
                                     "%fusion.2": "attn_core",
                                     "%fusion.3": "mlp"}
+
+
+def test_scope_map_counts_a_reference_modules_scope():
+    """A scope that only one kind of model has (``ssd``) is counted where
+    its configuration's module names it, and is unscoped elsewhere."""
+    import reference
+    line = ('  %fusion.7 = f32[4]{0} fusion(%p), metadata={op_name='
+            '"jit(step)/while/body/checkpoint/ssd/cumsum"}\n')
+    hlo = HLO.replace("ENTRY %main {\n", "ENTRY %main {\n" + line)
+    ssm = reference.load({"reference": "ssm"})
+    assert "%fusion.7" not in spans.scope_map(hlo)
+    got = spans.scope_map(hlo, spans.SCOPES + ssm.SCOPES)
+    assert got == dict(spans.scope_map(HLO), **{"%fusion.7": "ssd"})
+    runs = [("jit_step(1)", 0.0, 1.0)]
+    ops = [("%fusion.7 = f32[4]{0} fusion(%p)", 0.1, 0.4),
+           ("%fusion.3 = bf16[4,8]{1,0} fusion(%p)", 0.4, 0.6)]
+    assert spans.reduce(_device(runs, ops), scopes=got)["scope_s"] == \
+        pytest.approx({"ssd": 0.3, "mlp": 0.2})
 
 
 def test_scope_self_times_sum_with_the_unscoped_to_the_step():
@@ -165,12 +188,12 @@ def test_a_trace_with_no_program_spans_reads_nothing():
 def test_each_reader_on_a_record_built_by_hand(metric):
     rec = {"spans": {"input_s": [0.004, 0.005, 0.0047],
                      "handoff_s": [0.0006, 0.0005, 0.0009],
-                     "rounds": 340, "periods": 10, "idle_s": {},
+                     "asked_rounds": 34, "periods": 10, "idle_s": {},
                      "scope_s": {"attn_core": 0.1, "mlp": 0.06,
                                  "head_loss": 0.05, "optimizer": 0.02,
                                  spans.UNSCOPED: 0.01}}}
     want = {"train_loop.input_ms": 4.7, "executor.handoff_ms": 0.6,
-            "cws.rounds_per_task": 34.0, "train_step.attn_core_ms": 100.0,
+            "cws.rounds_per_task": 3.4, "train_step.attn_core_ms": 100.0,
             "train_step.mlp_ms": 60.0, "train_step.head_loss_ms": 50.0,
             "train_step.optimizer_ms": 20.0}[metric]
     assert R.reader(metric)(rec) == pytest.approx(want)
@@ -179,7 +202,7 @@ def test_each_reader_on_a_record_built_by_hand(metric):
 
 
 def test_the_chip_trace_with_scopes():
-    planes, bounds = spans.load(str(SCOPED))
+    planes, bounds = spans.load(trace.profile(str(SCOPED)))
     r = spans.reduce(planes, bounds,
                      spans.scope_map(SCOPED_HLO.read_text()))
     red = trace.reduce_file(str(SCOPED))
@@ -201,3 +224,24 @@ def test_the_chip_trace_with_scopes():
     assert idle.get(spans.NONE, 0.0) < 0.1 * sum(idle.values())
     assert sum(idle.values()) == pytest.approx(
         red["window_s"] - red["busy_s"])
+
+
+def test_a_traced_run_hands_its_trace_to_the_span_readers():
+    """``run.py`` reduces a traced run's profile with the step's scope map:
+    at smoke size on the CPU the program's host spans read (the CPU trace
+    has no TPU plane, so the device-time metrics read nothing)."""
+    import time
+
+    import jax
+    from smoke import smoke_spec
+    spec = smoke_spec("qwen1.5-0.5b")
+    spec["per_layer"] = R.load_spec("qwen1.5-0.5b.train-chunk1")["per_layer"]
+    spec["cell"]["trace_steps"] = 6
+    out = R.run(spec, 2147483801, 0.5, True, jax.devices()[:1],
+                time.monotonic())
+    got = out["result"]["metrics"]
+    assert out["result"]["correct"], out["result"]["checks"]
+    for m in ("train_loop.input_ms", "executor.handoff_ms",
+              "cws.rounds_per_task"):
+        assert got[m]["value"] > 0, m
+    assert not any(m.startswith("train_step.") for m in got)

@@ -5,17 +5,19 @@ from types import SimpleNamespace
 
 import pytest
 
+import reference
 import run as R
+
+CONFIG = {"reference": "dense", "num_hidden_layers": 1, "hidden_size": 4,
+          "num_attention_heads": 1, "num_key_value_heads": 1, "head_dim": 4,
+          "intermediate_size": 4, "vocab_size": 8}
 
 
 def _spec(chunk=1, check=3, step_s=0.5, trace_steps=4):
     return {"traffic": {"chunk": chunk, "batch": 8, "seq": 1024},
             "cell": {"check_steps": check, "step_s": step_s,
                      "trace_steps": trace_steps},
-            "config": {"family": "dense", "num_hidden_layers": 1,
-                       "hidden_size": 4, "num_attention_heads": 1,
-                       "num_key_value_heads": 1, "head_dim": 4,
-                       "intermediate_size": 4, "vocab_size": 8},
+            "config": CONFIG, "reference": reference.load(CONFIG),
             "peaks": {"chip": {"bf16_flops_per_s": 1e12}}}
 
 
@@ -98,7 +100,7 @@ def test_trace_readers():
     rec = {"trace": {"steps": 4, "window_s": 2.0, "busy_s": 1.5,
                      "step_device_s": [0.4, 0.5, 0.45, 0.5]},
            "flops_per_token": 1e6, "tokens_per_step": 1000, "chips": 1,
-           "peak_flops_per_s": 1e10}
+           "peaks": {"bf16_flops_per_s": 1e10}}
     assert R.reader("train_step.device_ms")(rec) == pytest.approx(475.0)
     assert R.reader("device.idle_pct")(rec) == pytest.approx(25.0)
     assert R.reader("train_step.mfu")(rec) == pytest.approx(

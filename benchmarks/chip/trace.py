@@ -30,11 +30,15 @@ MODULES, OPS = "XLA Modules", "XLA Ops"
 TOP = 10
 
 
-def load(path: str):
-    """The planes of a trace file as plain data:
-    ``{plane: {line: [(name, start_s, end_s), ...]}}``."""
+def profile(path: str):
+    """The trace file, parsed once for every reduction that reads it."""
     from jax.profiler import ProfileData
-    pd = ProfileData.from_file(path)
+    return ProfileData.from_file(path)
+
+
+def load(pd):
+    """The planes of a parsed trace (``profile``) as plain data:
+    ``{plane: {line: [(name, start_s, end_s), ...]}}``."""
     out: Dict[str, Dict[str, list]] = {}
     for plane in pd.planes:
         lines = {}
@@ -159,11 +163,11 @@ def reduce(planes) -> Optional[dict]:
 
 
 def reduce_file(path: str) -> Optional[dict]:
-    return reduce(load(path))
+    return reduce(load(profile(path)))
 
 
 if __name__ == "__main__":
-    planes = load(sys.argv[1])
+    planes = load(profile(sys.argv[1]))
     for name, lines in planes.items():
         print(name, {ln: len(evs) for ln, evs in lines.items()},
               file=sys.stderr)
