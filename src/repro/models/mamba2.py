@@ -70,17 +70,25 @@ def ssd_chunked(xh: jax.Array, dt: jax.Array, a: jax.Array,
 
     xh: (B, S, H, Pd) head inputs;  dt: (B, S, H) (post-softplus);
     a:  (H,) negative decay rates;  B_, C_: (B, S, G, N), H = G·R.
-    Returns (y: (B, S, H, Pd), final_state: (B, H, Pd, N)).
+    Returns (y: (B, S, H, Pd), final_state: (B, H, Pd, N) in float32).
+
+    The decays run in float32 whatever the inputs' dtype, as in mamba_ssm:
+    dt·a, its cumsum within a chunk (|Σ| reaches thousands at chunk 256,
+    where a bfloat16 step is 16–32), the segment sums, every exp of them
+    and the state carried across chunks. The matmul operands (x·dt, B, C,
+    the decays and the carried state) go in at ``xh``'s dtype.
     """
     Bb, S, H, Pd = xh.shape
     G, N = B_.shape[2], B_.shape[3]
     R = H // G
     assert S % chunk == 0, f"seq {S} not divisible by chunk {chunk}"
     nc = S // chunk
+    cdt = xh.dtype
+    dt = dt.astype(jnp.float32)
 
     # fold dt into x (the "discretised input"), dA per step
-    x_dt = xh * dt[..., None]                                   # (B,S,H,Pd)
-    dA = dt * a[None, None, :]                                  # (B,S,H) ≤ 0
+    x_dt = (xh * dt[..., None]).astype(cdt)                     # (B,S,H,Pd)
+    dA = dt * a.astype(jnp.float32)[None, None, :]              # (B,S,H) ≤ 0
 
     def r4(t, last):  # (B, S, ...) → (B, nc, chunk, ...)
         return t.reshape(Bb, nc, chunk, *last)
@@ -95,21 +103,21 @@ def ssd_chunked(xh: jax.Array, dt: jax.Array, a: jax.Array,
 
     # intra-chunk (dual / attention-like form)
     y_diag = jnp.einsum("bclgn,bcsgn,bgrcls,bcsgrp->bclgrp",
-                        Cc, Bc, L.astype(Cc.dtype), xc)
+                        Cc, Bc, L.astype(cdt), xc)
 
     # chunk summary states: (B, c, G, R, Pd, N)
     decay_states = jnp.exp(dA_cum[..., -1:] - dA_cum)           # (B,G,R,c,l)
     states = jnp.einsum("bclgn,bgrcl,bclgrp->bcgrpn",
-                        Bc, decay_states.astype(Bc.dtype), xc)
+                        Bc, decay_states.astype(cdt), xc)
 
     # inter-chunk recurrence h_{c+1} = h_c * exp(ΣdA_c) + S_c
     chunk_decay = jnp.exp(dA_cum[..., -1])                      # (B,G,R,c)
-    if h0 is None:
-        h0 = jnp.zeros((Bb, G, R, Pd, N), states.dtype)
+    h0 = (jnp.zeros((Bb, G, R, Pd, N), jnp.float32) if h0 is None
+          else h0.astype(jnp.float32))
 
     def step(h, inp):
         dec, s = inp                                            # (B,G,R), (B,G,R,Pd,N)
-        h_new = h * dec[..., None, None].astype(h.dtype) + s
+        h_new = h * dec[..., None, None] + s
         return h_new, h                                         # emit state *entering* chunk
 
     decay_t = chunk_decay.transpose(3, 0, 1, 2)                 # (c,B,G,R)
@@ -120,7 +128,7 @@ def ssd_chunked(xh: jax.Array, dt: jax.Array, a: jax.Array,
     # inter-chunk contribution
     state_decay = jnp.exp(dA_cum)                               # (B,G,R,c,l)
     y_off = jnp.einsum("bclgn,bcgrpn,bgrcl->bclgrp",
-                       Cc, h_in, state_decay.astype(Cc.dtype))
+                       Cc, h_in.astype(cdt), state_decay.astype(cdt))
 
     y = (y_diag + y_off).reshape(Bb, nc, chunk, H, Pd)
     return y.reshape(Bb, S, H, Pd), h_final.reshape(Bb, H, Pd, N)
@@ -154,8 +162,7 @@ def mamba_block(x: jax.Array, p: Dict[str, jax.Array], cfg: ModelConfig,
             y, _ = kops.ssd_scan(xh, dt.astype(x.dtype), a.astype(x.dtype),
                                  B_, C_, chunk=chunk)
         else:
-            y, _ = ssd_chunked(xh, dt.astype(x.dtype), a.astype(x.dtype),
-                               B_, C_, chunk=chunk)
+            y, _ = ssd_chunked(xh, dt, a, B_, C_, chunk=chunk)
     y = y + xh * p["d_skip"].astype(x.dtype)[None, None, :, None]
     y = y.reshape(Bb, S, d_in)
     y = rmsnorm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
@@ -232,7 +239,8 @@ def ssm_lm_schema(cfg: ModelConfig) -> Schema:
 
 def ssm_forward(cfg: ModelConfig, params, tokens: jax.Array,
                 remat: str = "block", use_pallas: bool = False):
-    x = params["embed"]["table"][tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"]["table"][tokens]
 
     def body(h, p):
         return h + mamba_block(rmsnorm(h, p["ln"], cfg.norm_eps), p, cfg,
@@ -243,7 +251,8 @@ def ssm_forward(cfg: ModelConfig, params, tokens: jax.Array,
                               policy=jax.checkpoint_policies.nothing_saveable)
     x, _ = jax.lax.scan(body, x, params["layers"])
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
+    with jax.named_scope("head_loss"):
+        logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
     return logits, jnp.zeros((), jnp.float32)
 
 

@@ -78,9 +78,9 @@ def make_train_step(model: Model, tcfg: TrainConfig, shape: ShapeConfig,
     """Returns (train_step, state_shardings, batch_shardings, state_specs).
 
     Attention is chosen here, by ``attention_path``; the SSD kernel runs
-    only where ``model.use_pallas`` asks for it, until mamba2's bf16
-    cumsum is fixed and its cell measures the kernel. Kernels compile
-    natively for a TPU mesh, in interpret mode for any other.
+    only where ``model.use_pallas`` asks for it: it has no backward pass,
+    so training takes XLA's ``ssd_chunked``. Kernels compile natively for
+    a TPU mesh, in interpret mode for any other.
     """
     model = Model(model.cfg,
                   attention_path(model, mesh, shape) == "pallas"

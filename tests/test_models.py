@@ -158,6 +158,65 @@ def test_window_ring_buffer_decode_matches_forward():
     assert max(errs) < 0.08, f"max rel err {max(errs):.4f}"
 
 
+def _ssm_reference():
+    """The chip benchmark's plain Mamba-2 reference
+    (``benchmarks/chip/references/ssm.py``) and its f32 matmul."""
+    import sys
+    from pathlib import Path
+    chip = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+    if str(chip) not in sys.path:
+        sys.path.append(str(chip))
+    import reference
+    return reference.load({"reference": "ssm"}), reference.MATMUL["f32"]
+
+
+@pytest.mark.parametrize("seed", [2, 7])   # |Σ dt·a| 504 and 417
+def test_mamba_block_keeps_the_ssd_decays_in_float32(seed, monkeypatch):
+    """One Mamba-2 layer at smoke widths but a row of 1,024 tokens in
+    chunks of 256, with ``a_log`` and ``dt_bias`` drawn as the reference
+    draws them, so that |Σ dt·a| within a chunk passes 256, where a
+    bfloat16 step is 2. What ``mamba_block`` hands ``ssd_chunked`` and what
+    it gets back are held against the reference's quadratic SSD in f32.
+
+    Tolerance 2e-2 on the relative norm of the difference: the matmul
+    operands (x·dt, B, C, the decays) are bfloat16, 2^-9 of relative
+    rounding each, and the readings are 3.2e-3 and 3.3e-3; with the cumsum
+    in bfloat16 they are 0.17 and 0.20."""
+    import dataclasses
+    from repro.models import mamba2
+    from repro.models.layers import rmsnorm
+    ssm, mm = _ssm_reference()
+    cfg = SMOKE_ARCHS["mamba2-370m"]
+    cfg = dataclasses.replace(cfg, ssm=dataclasses.replace(cfg.ssm,
+                                                           chunk=256))
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    p = jax.tree.map(lambda v: v[0],
+                     build_model(cfg).init(keys[0])["layers"])
+    p = dict(p, a_log=ssm.INITS["ssm_a"](keys[1], p["a_log"].shape),
+             dt_bias=ssm.INITS["dt_bias"](keys[2], p["dt_bias"].shape))
+    x = jax.random.normal(keys[3], (2, 1024, cfg.d_model), jnp.bfloat16)
+
+    seen = {}
+    base = mamba2.ssd_chunked
+
+    def spy(*args, **kw):
+        out = base(*args, **kw)
+        seen["args"], seen["y"] = args, out[0]
+        return out
+    monkeypatch.setattr(mamba2, "ssd_chunked", spy)
+    mamba2.mamba_block(rmsnorm(x, p["ln"], cfg.norm_eps), p, cfg)
+
+    xh, dt, a, B_, C_ = (jnp.asarray(v, jnp.float32) for v in seen["args"])
+    assert float(jnp.max(jnp.abs(jnp.cumsum(
+        (dt * a).reshape(2, 4, 256, -1), axis=2)))) > 256
+    want = jax.vmap(lambda *r: ssm._ssd(r[0], r[1], a, r[2], r[3], mm))(
+        xh, dt, B_, C_)
+    got = jnp.asarray(seen["y"], jnp.float32)
+    rel = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+    assert rel < 2e-2, rel
+    assert seen["args"][1].dtype == seen["args"][2].dtype == jnp.float32
+
+
 @pytest.mark.parametrize("arch", sorted(ARCHS))
 def test_full_config_specs_no_allocation(arch):
     """The FULL configs are only ever touched via ShapeDtypeStructs."""
