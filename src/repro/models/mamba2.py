@@ -53,10 +53,27 @@ def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
     return out + b
 
 
-def _segsum(x: jax.Array) -> jax.Array:
-    """x: (..., Q) → (..., Q, Q); [i, j] = Σ_{k=j+1..i} x[k]; -inf above diag."""
+def _prefix_sum(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sum of float32 x along its last axis, as one matmul
+    with the lower-triangular ones matrix.
+
+    XLA lowers ``jnp.cumsum`` to a reduce-window that sums a whole window
+    for each output, O(Q²) adds on the vector units, and its transpose in
+    the backward is another; a matmul runs on the MXU and transposes to a
+    matmul. HIGHEST keeps the sum at float32 accuracy (the TPU's default
+    is one bfloat16 pass), since exp(segsum) amplifies its error.
+    """
     Q = x.shape[-1]
-    cs = jnp.cumsum(x, -1)
+    tri = jnp.tril(jnp.ones((Q, Q), jnp.float32))             # [i, j]: j ≤ i
+    return jax.lax.dot_general(x, tri, (((x.ndim - 1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _segsum(cs: jax.Array) -> jax.Array:
+    """cs: (..., Q) inclusive prefix sums of x → (..., Q, Q);
+    [i, j] = cs[i] - cs[j] = Σ_{k=j+1..i} x[k]; -inf above diag."""
+    Q = cs.shape[-1]
     diff = cs[..., :, None] - cs[..., None, :]
     ok = jnp.tril(jnp.ones((Q, Q), bool))
     return jnp.where(ok, diff, -jnp.inf)
@@ -76,7 +93,9 @@ def ssd_chunked(xh: jax.Array, dt: jax.Array, a: jax.Array,
     dt·a, its cumsum within a chunk (|Σ| reaches thousands at chunk 256,
     where a bfloat16 step is 16–32), the segment sums, every exp of them
     and the state carried across chunks. The matmul operands (x·dt, B, C,
-    the decays and the carried state) go in at ``xh``'s dtype.
+    the decays and the carried state) go in at ``xh``'s dtype. The cumsum
+    is taken once, as a matmul (``_prefix_sum``), and feeds the segment
+    sums and every decay.
     """
     Bb, S, H, Pd = xh.shape
     G, N = B_.shape[2], B_.shape[3]
@@ -98,8 +117,8 @@ def ssd_chunked(xh: jax.Array, dt: jax.Array, a: jax.Array,
     Bc = r4(B_, (G, N))
     Cc = r4(C_, (G, N))
 
-    dA_cum = jnp.cumsum(dAc, axis=-1)                           # (B,G,R,c,l)
-    L = jnp.exp(_segsum(dAc))                                   # (B,G,R,c,l,l)
+    dA_cum = _prefix_sum(dAc)                                   # (B,G,R,c,l)
+    L = jnp.exp(_segsum(dA_cum))                                # (B,G,R,c,l,l)
 
     # intra-chunk (dual / attention-like form)
     y_diag = jnp.einsum("bclgn,bcsgn,bgrcls,bcsgrp->bclgrp",

@@ -177,6 +177,102 @@ def test_ssd_chunked_xla_matches_sequential():
         rtol=2e-3, atol=2e-3)
 
 
+def _ssd_chunk256_inputs(seed):
+    """``ssd_chunked`` inputs at chunk 256, with ``a_log`` and ``dt_bias``
+    drawn by the chip benchmark's Mamba-2 reference's own rules, so that
+    |Σ dt·a| within a chunk passes 256."""
+    import sys
+    from pathlib import Path
+    chip = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+    if str(chip) not in sys.path:
+        sys.path.append(str(chip))
+    import reference
+    inits = reference.load({"reference": "ssm"}).INITS
+    B, S, H, P, G, N = 1, 512, 8, 16, 2, 16
+    k = jax.random.split(jax.random.PRNGKey(seed), 6)
+    a = -jnp.exp(inits["ssm_a"](k[0], (H,)).astype(jnp.float32))
+    dt_bias = inits["dt_bias"](k[1], (H,)).astype(jnp.float32)
+    dt = jax.nn.softplus(0.5 * jax.random.normal(k[2], (B, S, H)) + dt_bias)
+    xh = jax.random.normal(k[3], (B, S, H, P))
+    B_ = 0.5 * jax.random.normal(k[4], (B, S, G, N))
+    C_ = 0.5 * jax.random.normal(k[5], (B, S, G, N))
+    most = float(jnp.max(jnp.abs(jnp.sum(
+        (dt * a).reshape(B, S // 256, 256, H), axis=2))))
+    assert most > 256, most
+    return xh, dt, a, B_, C_
+
+
+@pytest.mark.parametrize("seed", [2, 7])
+def test_ssd_chunked_prefix_sum_matmul_matches_cumsum_at_chunk_256(
+        seed, monkeypatch):
+    """At chunk 256 with |Σ dt·a| past 256, the prefix sum taken as a
+    triangular matmul gives the sequential oracle's output and final
+    state, and the same gradients in dt and a as ``jnp.cumsum`` (the
+    formulation it replaced) to 1e-4 of their norm."""
+    from repro.models import mamba2
+    xh, dt, a, B_, C_ = _ssd_chunk256_inputs(seed)
+    got, hf = mamba2.ssd_chunked(xh, dt, a, B_, C_, chunk=256)
+    want, hf_ref = ref.ssd_scan_ref(xh, dt, a, B_, C_)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-3, atol=2e-3)
+    np.testing.assert_allclose(
+        np.asarray(hf).reshape(hf_ref.shape), np.asarray(hf_ref),
+        rtol=2e-3, atol=2e-3)
+
+    wy = jax.random.normal(jax.random.PRNGKey(1), got.shape)
+    wh = jax.random.normal(jax.random.PRNGKey(2), hf.shape)
+
+    def loss(dt, a):
+        y, h = mamba2.ssd_chunked(xh, dt, a, B_, C_, chunk=256)
+        return jnp.sum(y * wy) + jnp.sum(h * wh)
+
+    grads = jax.grad(loss, argnums=(0, 1))(dt, a)
+    monkeypatch.setattr(mamba2, "_prefix_sum",
+                        lambda x: jnp.cumsum(x, axis=-1))
+    old = jax.grad(loss, argnums=(0, 1))(dt, a)
+    for g, o in zip(grads, old):
+        rel = float(jnp.linalg.norm(g - o) / jnp.linalg.norm(o))
+        assert rel < 1e-4, rel
+
+
+def _primitive_names(jaxpr):
+    """Every primitive in a jaxpr, through the sub-jaxprs of scans,
+    checkpoints and calls."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _primitive_names(sub)
+
+
+def test_ssd_chunked_training_gradient_takes_no_cumsum(monkeypatch):
+    """The gradient of a remat'd ``ssd_chunked`` (as the training step
+    takes it) holds no ``cumsum``, which XLA lowers to an O(Q²)
+    reduce-window forward and backward; with ``jnp.cumsum`` put back as
+    the prefix sum, the same walk finds it."""
+    from repro.models import mamba2
+    args = _ssd_chunk256_inputs(2)
+
+    def primitives():
+        # a fresh function each time: jax.checkpoint caches its trace
+        def loss(xh, dt, a, B_, C_):
+            y, h = mamba2.ssd_chunked(xh, dt, a, B_, C_, chunk=256)
+            return jnp.sum(y) + jnp.sum(h)
+        step = jax.grad(jax.checkpoint(
+            loss, policy=jax.checkpoint_policies.nothing_saveable),
+            argnums=(0, 1, 2, 3, 4))
+        return set(_primitive_names(jax.make_jaxpr(step)(*args).jaxpr))
+
+    names = primitives()
+    assert "scan" in names and "dot_general" in names
+    assert "cumsum" not in names
+    monkeypatch.setattr(mamba2, "_prefix_sum",
+                        lambda x: jnp.cumsum(x, axis=-1))
+    assert "cumsum" in primitives()
+
+
 def test_attention_q_chunking_equivalence():
     """The XLA reference attention must be invariant to query chunking."""
     from repro.models.layers import attention
