@@ -5,7 +5,8 @@
 #                     writes BENCH_sched_scale.json (CI uploads it as an
 #                     artifact; override the path with BENCH_JSON=...)
 #   make bench-smoke  the same bench at CI scale (~30 s)
-#   make bench-all    every paper-artifact benchmark (benchmarks/run.py)
+#   make bench-all    every scheduler paper-artifact benchmark, on the
+#                     simulator (benchmarks/run.py)
 #   make golden       regenerate tests/golden/ scheduling-trace snapshots
 
 PYTHON ?= python

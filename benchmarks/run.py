@@ -6,7 +6,6 @@
   predictors     §5 runtime prediction (Lotaru vs mean baselines)
   resource_pred  §5 peak-memory prediction (wastage/OOM table)
   provenance     §4 provenance store throughput/export
-  roofline       §Roofline table from the dry-run artifacts (if present)
   sched_scale    incremental scheduling core vs legacy full scans at
                  10×500-task multi-workflow scale
 
@@ -25,7 +24,6 @@ def main() -> None:
         bench_predictors,
         bench_provenance,
         bench_resource_pred,
-        bench_roofline,
         bench_sched_scale,
         bench_strategies,
     )
@@ -36,7 +34,6 @@ def main() -> None:
         ("predictors", bench_predictors.run),
         ("resource_pred", bench_resource_pred.run),
         ("provenance", bench_provenance.run),
-        ("roofline", bench_roofline.run),
         ("sched_scale", bench_sched_scale.run),
     ]
     rows = []

@@ -38,7 +38,7 @@ def main() -> None:
                        microbatch_per_device=B)
     mesh = make_host_mesh()
     step, _, _, _ = make_train_step(model, tcfg,
-                                    ShapeConfig("ft", S, B, "train"), mesh)
+                                    ShapeConfig("ft", S, B), mesh)
     jstep = jax.jit(step)
     pipe = TokenPipeline(DataConfig(vocab=cfg.vocab, seq_len=S,
                                     global_batch=B, seed=3))

@@ -38,7 +38,7 @@ def main() -> None:
     cfg = get_config("qwen1.5-0.5b", smoke=True)
     model = build_model(cfg)
     B, S = 8, 128
-    shape = ShapeConfig("quickstart", S, B, "train")
+    shape = ShapeConfig("quickstart", S, B)
     tcfg = TrainConfig(learning_rate=3e-3, warmup_steps=10,
                        microbatch_per_device=B)
     mesh = make_host_mesh()

@@ -8,9 +8,7 @@ from .base import (  # noqa: F401
     HybridConfig,
     ModelConfig,
     MoEConfig,
-    RunConfig,
     ShapeConfig,
-    SHAPES,
     SSMConfig,
     TrainConfig,
     VisionConfig,
@@ -52,15 +50,3 @@ def get_config(arch: str, smoke: bool = False) -> ModelConfig:
     except KeyError:
         raise ValueError(f"unknown arch {arch!r}; available: {sorted(ARCHS)}") from None
 
-
-def cells(include_skipped: bool = False):
-    """All (arch × shape) dry-run cells; skipped ones carry their reason."""
-    out = []
-    for arch, cfg in ARCHS.items():
-        for shape_name, shape in SHAPES.items():
-            skipped = shape_name in cfg.skip_shapes
-            if skipped and not include_skipped:
-                continue
-            out.append((arch, shape_name,
-                        cfg.skip_reasons.get(shape_name) if skipped else None))
-    return out

@@ -1,15 +1,15 @@
-"""Model / run configuration system.
+"""Model and training configuration.
 
 One ``ModelConfig`` describes any of the assigned architectures; family-
-specific knobs live in optional sub-configs. ``ShapeConfig`` describes the
-four assigned input shapes. ``RunConfig`` binds (arch × shape × mesh ×
-training knobs) — the unit the launcher and dry-run consume.
+specific knobs live in optional sub-configs. ``ShapeConfig`` is the batch
+a train step takes (sequence length × global batch); ``TrainConfig`` holds
+the step's training knobs.
 """
 from __future__ import annotations
 
 import dataclasses
-from dataclasses import dataclass, field
-from typing import Any, Dict, Optional, Tuple
+from dataclasses import dataclass
+from typing import Any, Optional
 
 
 @dataclass(frozen=True)
@@ -85,28 +85,17 @@ class ModelConfig:
     vision: Optional[VisionConfig] = None
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
-    # which input shapes this arch supports (skips recorded in DESIGN.md)
-    skip_shapes: Tuple[str, ...] = ()
-    skip_reasons: Dict[str, str] = field(default_factory=dict, hash=False)
 
     @property
     def head_dim_(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
 
-    @property
-    def sub_quadratic(self) -> bool:
-        return (self.family in ("ssm", "hybrid") or self.window > 0
-                or self.local_global > 0)
-
     def scaled(self, **overrides: Any) -> "ModelConfig":
         return dataclasses.replace(self, **overrides)
 
-    # ---- analytic parameter counts (→ MODEL_FLOPS in §Roofline) ----
     def param_count(self) -> int:
+        """Analytic parameter count (the schema holds the exact one)."""
         return _param_count(self)
-
-    def active_param_count(self) -> int:
-        return _param_count(self, active_only=True)
 
 
 def _attn_params(cfg: ModelConfig) -> int:
@@ -133,7 +122,7 @@ def _ssm_params(cfg: ModelConfig) -> int:
     return in_proj + conv + out + 2 * nh + d_in   # A, dt_bias, D, norm
 
 
-def _param_count(cfg: ModelConfig, active_only: bool = False) -> int:
+def _param_count(cfg: ModelConfig) -> int:
     n = cfg.vocab * cfg.d_model                     # embed
     if not cfg.tie_embeddings:
         n += cfg.vocab * cfg.d_model                # lm head
@@ -146,10 +135,9 @@ def _param_count(cfg: ModelConfig, active_only: bool = False) -> int:
     elif cfg.family == "moe":
         m = cfg.moe
         assert m is not None
-        n_e = m.top_k if active_only else m.n_experts
         layer = (
             _attn_params(cfg)
-            + n_e * _mlp_params(cfg.d_model, m.d_ff_expert or cfg.d_ff)
+            + m.n_experts * _mlp_params(cfg.d_model, m.d_ff_expert or cfg.d_ff)
             + cfg.d_model * m.n_experts               # router
             + per_layer_norms
         )
@@ -173,42 +161,23 @@ def _param_count(cfg: ModelConfig, active_only: bool = False) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Input shapes (assigned)
+# The train step's batch and knobs
 # ---------------------------------------------------------------------------
 @dataclass(frozen=True)
 class ShapeConfig:
     name: str
     seq_len: int
     global_batch: int
-    kind: str                        # train | prefill | decode
-
-
-SHAPES: Dict[str, ShapeConfig] = {
-    "train_4k": ShapeConfig("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524_288, 1, "decode"),
-}
 
 
 @dataclass(frozen=True)
 class TrainConfig:
+    """ZeRO-1 and ZeRO-2 are always on and the AdamW moments are bf16
+    (``runtime/train.py``); the master weights stay f32."""
+
     microbatch_per_device: int = 1   # grad-accum chunk size
     remat: str = "block"             # none | block | full
     learning_rate: float = 3e-4
     warmup_steps: int = 100
     weight_decay: float = 0.1
     grad_clip: float = 1.0
-    zero1: bool = True               # shard optimizer state over data axis
-    zero2: bool = True               # accumulate grads in the ZeRO sharding
-    opt_dtype: str = "bfloat16"      # moments dtype (master stays f32)
-    grad_compression: str = "none"   # none | int8 (cross-pod all-reduce)
-    seed: int = 0
-
-
-@dataclass(frozen=True)
-class RunConfig:
-    model: ModelConfig
-    shape: ShapeConfig
-    train: TrainConfig = field(default_factory=TrainConfig)
-    multi_pod: bool = False

@@ -13,8 +13,6 @@ CONFIG = ModelConfig(
     vocab=65024,
     qkv_bias=True,
     rope_fraction=0.5,      # "RoPE 2d": rotate half of each head dim
-    skip_shapes=("long_500k",),
-    skip_reasons={"long_500k": "pure full attention"},
 )
 
 SMOKE = CONFIG.scaled(

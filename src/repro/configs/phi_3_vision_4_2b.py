@@ -13,8 +13,6 @@ CONFIG = ModelConfig(
     vocab=32064,
     rope_theta=10000.0,
     vision=VisionConfig(n_patches=576, patch_dim=1024),
-    skip_shapes=("long_500k",),
-    skip_reasons={"long_500k": "pure full attention backbone"},
 )
 
 SMOKE = CONFIG.scaled(

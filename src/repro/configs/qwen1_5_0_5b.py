@@ -12,8 +12,6 @@ CONFIG = ModelConfig(
     vocab=151936,
     qkv_bias=True,
     tie_embeddings=True,
-    skip_shapes=("long_500k",),
-    skip_reasons={"long_500k": "pure full attention"},
 )
 
 SMOKE = CONFIG.scaled(

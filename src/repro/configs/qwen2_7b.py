@@ -12,8 +12,6 @@ CONFIG = ModelConfig(
     vocab=152064,
     qkv_bias=True,
     rope_theta=1e6,
-    skip_shapes=("long_500k",),
-    skip_reasons={"long_500k": "pure full attention"},
 )
 
 SMOKE = CONFIG.scaled(

@@ -13,9 +13,6 @@ CONFIG = ModelConfig(
     vocab=151936,
     rope_theta=1e6,
     moe=MoEConfig(n_experts=128, top_k=8, d_ff_expert=768),
-    skip_shapes=("long_500k",),
-    skip_reasons={"long_500k": "pure full attention (no SWA/SSM); "
-                               "O(seq) KV at 500k is out of scope per brief"},
 )
 
 SMOKE = CONFIG.scaled(

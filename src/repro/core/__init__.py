@@ -37,8 +37,6 @@ from .predict import (  # noqa: F401
     FeedbackMemoryPredictor,
     LotaruPredictor,
     NodeProfile,
-    RooflinePrior,
-    RooflineTerms,
 )
 from .provenance import NodeEvent, ProvenanceStore, TaskTrace  # noqa: F401
 from .scheduler import (  # noqa: F401

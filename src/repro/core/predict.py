@@ -11,11 +11,6 @@ Implements the prediction approaches the paper plans to integrate:
   Witt et al. (HPCS'19) / Tovar et al.: linear model of peak memory vs input
   size with a safety margin; on under-provisioning (OOM) the scheduler retries
   with a doubled allocation. Predicts low wastage without failures.
-* ``RooflinePrior`` — TPU adaptation (DESIGN.md §2): for gang-scheduled JAX
-  step tasks the dry-run's roofline terms (compute/memory/collective seconds)
-  give an *analytic* prior runtime, which seeds the Bayesian regression where
-  Lotaru would use microbenchmarks. This connects the scheduler to the
-  compiled-artifact analysis in ``launch/dryrun.py``.
 
 All predictors read ONLY from the provenance store / explicit observations —
 never from scheduler internals — mirroring how CWSI plugins are wired.
@@ -224,59 +219,3 @@ class FeedbackMemoryPredictor:
         base = max(base, self.floor)
         return int(base * (2 ** attempt))
 
-
-# --------------------------------------------------------------------------
-# Roofline prior for gang-scheduled JAX step tasks (TPU adaptation).
-# --------------------------------------------------------------------------
-@dataclass(frozen=True)
-class RooflineTerms:
-    """The three §Roofline terms for one compiled step (seconds)."""
-
-    compute_s: float
-    memory_s: float
-    collective_s: float
-
-    @property
-    def step_s(self) -> float:
-        # max(compute, memory) assumes perfect overlap of HBM traffic with
-        # MXU work; collectives overlap partially (0.5 exposure default).
-        return max(self.compute_s, self.memory_s) + 0.5 * self.collective_s
-
-    @property
-    def dominant(self) -> str:
-        terms = {
-            "compute": self.compute_s,
-            "memory": self.memory_s,
-            "collective": self.collective_s,
-        }
-        return max(terms, key=terms.__getitem__)
-
-
-class RooflinePrior:
-    """Analytic runtime prior for step-programs, seeded from dry-run JSON.
-
-    ``register(name, terms, steps_per_task)`` installs the prior;
-    ``seed(lotaru)`` injects it into a LotaruPredictor as synthetic
-    observations so the Bayesian model starts at the analytic estimate and
-    refines online — exactly the cold-start role microbenchmarks play in
-    Lotaru.
-    """
-
-    def __init__(self) -> None:
-        self.terms: Dict[str, Tuple[RooflineTerms, int]] = {}
-
-    def register(self, name: str, terms: RooflineTerms, steps_per_task: int = 1) -> None:
-        self.terms[name] = (terms, steps_per_task)
-
-    def predict(self, name: str) -> Optional[float]:
-        entry = self.terms.get(name)
-        if entry is None:
-            return None
-        t, steps = entry
-        return t.step_s * steps
-
-    def seed(self, lotaru: LotaruPredictor, pseudo_obs: int = 3,
-             nominal_input: int = 1 << 30) -> None:
-        for name, (t, steps) in self.terms.items():
-            for _ in range(pseudo_obs):
-                lotaru.observe(name, nominal_input, t.step_s * steps)

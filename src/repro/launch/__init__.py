@@ -1,1 +1,1 @@
-# Launchers: mesh builders, the multi-pod dry-run, train/serve drivers.
+# Launchers: the mesh builders and the CWS-orchestrated training driver.

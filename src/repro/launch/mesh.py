@@ -1,8 +1,7 @@
-"""Production mesh builders.
+"""Mesh builders.
 
 Defined as FUNCTIONS so importing this module never touches jax device
-state. The dry-run process sets XLA_FLAGS to fake 512 host devices *before*
-any jax import; everything else sees the real topology.
+state.
 
 Every mesh is built here with Auto axis types: the train step places its
 tensors with ``with_sharding_constraint``, which accepts only Auto axes.
@@ -21,21 +20,8 @@ def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
                          devices=devices)
 
 
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
 def make_host_mesh(devices: Optional[Sequence[Any]] = None) -> Mesh:
     """``devices`` (default: every device of this host) on the ``model``
     axis."""
     devices = list(devices) if devices is not None else jax.devices()
     return make_mesh((1, len(devices)), ("data", "model"), devices)
-
-
-def mesh_device_count(mesh: Mesh) -> int:
-    n = 1
-    for v in mesh.shape.values():
-        n *= v
-    return n

@@ -87,7 +87,7 @@ def run_training(cfg: ModelConfig, *, steps: int, chunk: int, batch: int,
     mesh = mesh if mesh is not None else make_host_mesh()
     model = build_model(cfg)
 
-    shape = ShapeConfig("driver", seq, batch, "train")
+    shape = ShapeConfig("driver", seq, batch)
     attention = attention_path(model, mesh, shape)
     log(f"[train] arch={cfg.name} params={model.n_params():,} "
         f"mesh={dict(mesh.shape)} attention={attention}")

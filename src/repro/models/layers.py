@@ -3,7 +3,7 @@
 Parameters are described by a *schema*: a nested dict whose leaves are
 ``P(shape, axes, init)``. The same schema yields
   * ``init_params``  — materialised arrays (smoke tests / real training),
-  * ``param_specs``  — ShapeDtypeStructs (dry-run: zero allocation),
+  * ``param_specs``  — ShapeDtypeStructs (lowering with no allocation),
   * ``param_axes``   — logical-axis tuples (sharding rules input).
 Logical axis names used throughout:
   "embed" (d_model), "heads" (q heads × head_dim fused), "kv_heads",

@@ -1,15 +1,12 @@
 """Unified model API over all assigned architecture families.
 
 ``Model`` is a thin, stateless dispatcher: one schema (→ init / specs /
-logical axes from a single source of truth), one ``loss`` for training, one
-``prefill``/``decode_step`` pair for serving. Everything is a pure function
+logical axes from a single source of truth), one ``loss`` for training, and
+``init_cache``/``decode_step`` for serving. Everything is a pure function
 of (params, batch) so pjit/shard_map wrap it directly.
 """
 from __future__ import annotations
 
-import math
-from dataclasses import dataclass
-from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -147,59 +144,26 @@ class Model:
             return encdec.decode_step(cfg, params, cache, token, pos)
         raise ValueError(cfg.family)
 
-    def prefill(self, params: Dict[str, Any], tokens: jax.Array,
-                max_len: int, extra: Optional[Dict[str, jax.Array]] = None,
-                ) -> Tuple[jax.Array, Dict[str, Any]]:
-        cfg = self.cfg
-        if cfg.family in ("dense", "moe", "vlm"):
-            patches = (extra or {}).get("patches")
-            return transformer.prefill(cfg, params, tokens, max_len, patches)
-        raise NotImplementedError(
-            f"prefill-with-cache for family {cfg.family}; the serve path "
-            "uses decode-from-empty-cache for SSM/hybrid (state is O(1))")
-
-    # ---------------- dry-run inputs ----------------
+    # ---------------- train-step inputs ----------------
     def input_specs(self, shape: ShapeConfig) -> Dict[str, Any]:
-        """ShapeDtypeStruct stand-ins for every model input of this cell."""
+        """ShapeDtypeStruct stand-ins for a train step's batch."""
         cfg = self.cfg
         B, S = shape.global_batch, shape.seq_len
         i32 = jnp.int32
-        if shape.kind == "train":
-            specs = {
-                "tokens": jax.ShapeDtypeStruct((B, S), i32),
-                "labels": jax.ShapeDtypeStruct((B, S), i32),
-            }
-            if cfg.family == "vlm":
-                assert cfg.vision is not None
-                specs["patches"] = jax.ShapeDtypeStruct(
-                    (B, cfg.vision.n_patches, cfg.vision.patch_dim),
-                    self.param_dtype)
-            if cfg.family == "audio":
-                assert cfg.encdec is not None
-                specs["frames"] = jax.ShapeDtypeStruct(
-                    (B, cfg.encdec.n_frames, cfg.d_model), self.param_dtype)
-            return specs
-        if shape.kind == "prefill":
-            specs = {"tokens": jax.ShapeDtypeStruct((B, S), i32)}
-            if cfg.family == "vlm":
-                specs["patches"] = jax.ShapeDtypeStruct(
-                    (B, cfg.vision.n_patches, cfg.vision.patch_dim),
-                    self.param_dtype)
-            if cfg.family == "audio":
-                specs["frames"] = jax.ShapeDtypeStruct(
-                    (B, cfg.encdec.n_frames, cfg.d_model), self.param_dtype)
-            return specs
-        # decode: one new token against a seq_len cache
-        return {
-            "cache": self.cache_specs(B, S),
-            "token": jax.ShapeDtypeStruct((B,), i32),
-            "pos": jax.ShapeDtypeStruct((), i32),
+        specs = {
+            "tokens": jax.ShapeDtypeStruct((B, S), i32),
+            "labels": jax.ShapeDtypeStruct((B, S), i32),
         }
-
-    # ---------------- analytics (§Roofline) ----------------
-    def model_flops_per_token(self) -> float:
-        """6·N (dense) / 6·N_active (MoE) — FLOPs per trained token."""
-        return 6.0 * self.cfg.active_param_count()
+        if cfg.family == "vlm":
+            assert cfg.vision is not None
+            specs["patches"] = jax.ShapeDtypeStruct(
+                (B, cfg.vision.n_patches, cfg.vision.patch_dim),
+                self.param_dtype)
+        if cfg.family == "audio":
+            assert cfg.encdec is not None
+            specs["frames"] = jax.ShapeDtypeStruct(
+                (B, cfg.encdec.n_frames, cfg.d_model), self.param_dtype)
+        return specs
 
 
 def build_model(cfg: ModelConfig, use_pallas: Optional[bool] = None) -> Model:

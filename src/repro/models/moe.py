@@ -4,14 +4,11 @@ Dispatch is **gather/scatter based** (sorted-position via one-hot cumsum),
 NOT the GShard einsum form: the (tokens × experts × capacity) dispatch einsum
 would cost T·E·C·d FLOPs — for qwen3's 128 experts that would exceed the
 expert compute itself by 100×. Here positions are integer bookkeeping
-(no matmul FLOPs) and the only matmuls are the expert GEMMs, so the §Roofline
-"useful FLOPs" ratio stays honest. The Pallas ``moe_gmm`` kernel replaces the
-expert einsum on TPU; this XLA path is the oracle.
+(no matmul FLOPs) and the only matmuls are the expert GEMMs. The Pallas
+``moe_gmm`` kernel computes the same expert GEMMs; no model calls it yet.
 """
 from __future__ import annotations
 
-import contextlib
-import contextvars
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
@@ -21,38 +18,15 @@ from jax.sharding import PartitionSpec
 from ..configs.base import MoEConfig
 from .layers import P, Schema
 
-# Expert-parallel mode (serve path): dispatch buffers shard expert-major to
-# match EP weights, instead of capacity-major (the training layout). Set at
-# trace time by the serve-step factories.
-_EP_MODE: contextvars.ContextVar[bool] = contextvars.ContextVar(
-    "moe_ep_mode", default=False)
-
-
-@contextlib.contextmanager
-def ep_mode():
-    tok = _EP_MODE.set(True)
-    try:
-        yield
-    finally:
-        _EP_MODE.reset(tok)
-
 
 def _constrain(x: jax.Array, *axes) -> jax.Array:
-    """Best-effort sharding constraint: tries progressively smaller axis
-    sets so the same model code runs on production meshes (pod/data/model),
-    single-pod meshes, and the 1-device test mesh."""
-    def drop_pod(a):
-        if isinstance(a, tuple):
-            t = tuple(x for x in a if x != "pod")
-            return t if len(t) > 1 else (t[0] if t else None)
-        return None if a == "pod" else a
-
-    for spec in (axes, tuple(drop_pod(a) for a in axes)):
-        try:
-            return jax.lax.with_sharding_constraint(x, PartitionSpec(*spec))
-        except Exception:  # noqa: BLE001 — no mesh / missing axis
-            continue
-    return x
+    """Best-effort sharding constraint: ``x`` placed by ``axes`` on the
+    mesh in context, or ``x`` itself where no mesh is in context or the
+    mesh lacks one of the axes."""
+    try:
+        return jax.lax.with_sharding_constraint(x, PartitionSpec(*axes))
+    except (RuntimeError, ValueError):   # no mesh in context / axis missing
+        return x
 
 
 def moe_schema(d_model: int, moe: MoEConfig) -> Schema:
@@ -104,30 +78,23 @@ def moe_ffn(x: jax.Array, p: Dict[str, jax.Array], moe: MoEConfig,
     xk = jnp.repeat(xt, K, axis=0)                              # token per slot
     contrib = jnp.where(keep[:, None], xk, 0)
     # dispatch buffers are the MoE memory hot-spot (E·C·d and E·C·ff): shard
-    # capacity over the data axes and the expert hidden dim over model —
-    # without constraints they (and their backward cotangents) replicate
-    # per device (~180 GiB at 32k prefill). The 2-D indexed scatter/gather
-    # keeps (E, C, d) shape throughout so one constraint covers fwd + bwd.
+    # capacity over the data axis (so gradient accumulation stays
+    # data-local) and the expert hidden dim over model — without
+    # constraints they (and their backward cotangents) replicate per
+    # device. The 2-D indexed scatter/gather keeps (E, C, d) shape
+    # throughout so one constraint covers fwd + bwd.
     buf = jnp.zeros((E, capacity, d), x.dtype).at[flat_e, pos_c].add(contrib)
-    ep = _EP_MODE.get()
-    if ep:   # serve: expert-major (the scatter IS the all-to-all)
-        buf = _constrain(buf, ("pod", "data"), None, None)
-    else:    # train: capacity-major (grad accumulation stays data-local)
-        buf = _constrain(buf, None, ("pod", "data"), None)
+    buf = _constrain(buf, None, "data", None)
 
     # expert GEMMs (the only matmul FLOPs in the MoE layer)
     g = jnp.einsum("ecd,edf->ecf", buf, p["w_gate"])
     u = jnp.einsum("ecd,edf->ecf", buf, p["w_up"])
-    if ep:
-        h = _constrain(jax.nn.silu(g) * u, ("pod", "data"), None, "model")
-    else:
-        h = _constrain(jax.nn.silu(g) * u, None, ("pod", "data"), "model")
+    h = _constrain(jax.nn.silu(g) * u, None, "data", "model")
     out = jnp.einsum("ecf,efd->ecd", h, p["w_down"])            # (E, C, d)
-    out = _constrain(out, ("pod", "data") if ep else None,
-                     None if ep else ("pod", "data"), None)
+    out = _constrain(out, None, "data", None)
 
     y_slots = out[flat_e, pos_c]                                # (TK, d)
-    y_slots = _constrain(y_slots, ("pod", "data"), None)
+    y_slots = _constrain(y_slots, "data", None)
     w = (gate.reshape(T * K) * keep).astype(x.dtype)
     y = (y_slots * w[:, None]).reshape(T, K, d).sum(axis=1)
     return y.reshape(B, S, d), aux.astype(jnp.float32)

@@ -11,8 +11,6 @@ context this is the difference between 4 GB and 500 GB of cache.
 """
 from __future__ import annotations
 
-import math
-from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -42,7 +40,7 @@ SEQ_SHARD_MIN_BYTES = 256 << 20
 
 def maybe_seq_shard(h: jax.Array) -> jax.Array:
     if h.ndim == 3 and h.size * h.dtype.itemsize > SEQ_SHARD_MIN_BYTES:
-        return _constrain(h, ("pod", "data"), "model", None)
+        return _constrain(h, "data", "model", None)
     return h
 
 
@@ -297,70 +295,3 @@ def decode_step(cfg: ModelConfig, params: Dict[str, Any],
     logits = unembed(cfg, params, x)[:, 0, :]
     return logits, new_cache
 
-
-def prefill(cfg: ModelConfig, params: Dict[str, Any], tokens: jax.Array,
-            max_len: int, patches: Optional[jax.Array] = None,
-            ) -> Tuple[jax.Array, Dict[str, Any]]:
-    """Run the full prompt, build a cache of size max_len, return
-    (last-position logits, cache). Prefill attention is the forward path."""
-    x = embed_inputs(cfg, params, tokens, patches)
-    B, S, _ = x.shape
-    positions = jnp.arange(S)[None, :]
-    pat = layer_pattern(cfg)
-    g = n_groups(cfg)
-    cache = init_cache(cfg, B, max_len, x.dtype)
-
-    def group_body(carry, inp):
-        h = carry
-        gp, gi = inp
-        new_kv = {knd: {"k": [], "v": []} for knd in cache}
-        for i, kind in enumerate(pat):
-            pi = jax.tree.map(lambda a: a[i], gp)
-            hh = rmsnorm(h, pi["ln1"], cfg.norm_eps)
-            q, k, v = qkv_project(hh, pi["attn"], cfg.n_heads,
-                                  cfg.n_kv_heads, cfg.head_dim_)
-            q = apply_rope(q, positions, fraction=cfg.rope_fraction,
-                           theta=cfg.rope_theta)
-            k = apply_rope(k, positions, fraction=cfg.rope_fraction,
-                           theta=cfg.rope_theta)
-            w = _window_of(cfg, kind)
-            o = attention(q, k, v, causal=True, window=w)
-            h = h + jnp.einsum("bsh,hd->bsd", o.reshape(B, S, -1),
-                               pi["attn"]["wo"])
-            hh = rmsnorm(h, pi["ln2"], cfg.norm_eps)
-            if cfg.family == "moe":
-                y, _ = moe_ffn(hh, pi["ffn"], cfg.moe)
-            else:
-                y = swiglu(hh, pi["ffn"]["w_gate"], pi["ffn"]["w_up"],
-                           pi["ffn"]["w_down"])
-            h = h + y
-            new_kv[kind]["k"].append(_to_cache_slots(k, w, max_len))
-            new_kv[kind]["v"].append(_to_cache_slots(v, w, max_len))
-        out = {knd: {kk: jnp.stack(vv) for kk, vv in d.items()}
-               for knd, d in new_kv.items()}
-        return h, out
-
-    x, kv = jax.lax.scan(group_body, x,
-                         (params["blocks"], jnp.arange(g)))
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = unembed(cfg, params, x[:, -1:, :])[:, 0, :]
-    return logits, kv
-
-
-def _to_cache_slots(k: jax.Array, window: int, max_len: int) -> jax.Array:
-    """Lay prefill K/V into cache slots. k: (B, S, hkv, hd)."""
-    B, S, hkv, hd = k.shape
-    if window == 0:
-        slots = max_len
-        pad = slots - S
-        assert pad >= 0, (S, max_len)
-        return jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    slots = min(window, max_len)
-    # last `slots` tokens, placed at their ring positions (pos % slots);
-    # for S % slots == 0 the ring is identity on the tail.
-    tail = k[:, -slots:, :, :] if S >= slots else jnp.pad(
-        k, ((0, 0), (0, slots - S), (0, 0), (0, 0)))
-    if S >= slots:
-        shift = S % slots
-        tail = jnp.roll(tail, shift, axis=1)
-    return tail

@@ -1,7 +1,1 @@
 from .adamw import AdamW, AdamWState, global_norm, warmup_cosine  # noqa: F401
-from .compress import (  # noqa: F401
-    compressed_psum_pod,
-    dequantize_int8,
-    error_feedback_update,
-    quantize_int8,
-)

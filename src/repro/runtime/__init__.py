@@ -1,10 +1,7 @@
-# Distributed runtime: sharding rules, train/serve step factories,
-# the CWS-driven orchestrator, and fault handling.
+# Distributed runtime: sharding rules, the train step factory, continuous
+# batching, the CWS-driven orchestrator, and fault handling.
 from .sharding import (  # noqa: F401
     base_rules,
-    batch_axes,
-    cache_axes,
-    decode_rules,
     input_axes,
     shardings_for_tree,
     spec_for,

@@ -5,9 +5,9 @@ run is not a monolithic loop but a **workflow DAG** — step-chunks chained by
 dependency, with eval / checkpoint / export tasks branching off — submitted
 through the CWSI so the CWS (inside the resource manager) owns ordering and
 placement. Benefits inherited for free: workflow-aware priorities across
-concurrent jobs, provenance of every chunk, online runtime prediction
-(seeded by the roofline prior), speculative re-execution of straggling
-chunks, and retry-with-doubling on OOM-failed evals.
+concurrent jobs, provenance of every chunk, online runtime prediction,
+speculative re-execution of straggling chunks, and retry-with-doubling on
+OOM-failed evals.
 """
 from __future__ import annotations
 
@@ -17,7 +17,6 @@ from typing import Any, Callable, Dict, List, Optional
 
 from ..core.cwsi import CWSIClient, CWSIServer
 from ..core.dag import DataRef, Resources, TaskSpec, WorkflowDAG
-from ..core.predict import RooflinePrior, RooflineTerms
 from ..core.scheduler import CommonWorkflowScheduler
 from ..cluster.executor import LocalExecutor
 from ..cluster.nodes import cpu_node
@@ -36,7 +35,6 @@ class TrainJobSpec:
                                      # (0 = no preemption-survivable progress)
     elastic: tuple = ()              # allowed narrower gang widths (ints < nodes)
     model_parallel: int = 1          # mesh model axis, pins resize feasibility
-    roofline: Optional[RooflineTerms] = None
 
 
 def _validated_elastic(spec: TrainJobSpec) -> List[int]:
@@ -148,16 +146,13 @@ class LocalRuntime:
     """CWS + CWSI + LocalExecutor bundle for running workflows for real."""
 
     def __init__(self, n_nodes: int = 2, cpus: float = 4.0,
-                 strategy: str = "rank_min_rr",
-                 roofline: Optional[RooflinePrior] = None) -> None:
+                 strategy: str = "rank_min_rr") -> None:
         from ..core.predict import FeedbackMemoryPredictor, LotaruPredictor
 
         self.executor = LocalExecutor(
             [cpu_node(f"local-{i}", cpus=cpus, mem_gib=8)
              for i in range(n_nodes)])
         self.predictor = LotaruPredictor()
-        if roofline is not None:
-            roofline.seed(self.predictor)
         self.cws = CommonWorkflowScheduler(
             adapter=self.executor,
             strategy=strategy,

@@ -1,97 +1,22 @@
-"""Serving runtime: decode/prefill step factories + continuous batching.
+"""Serving runtime: continuous batching.
 
-``make_serve_step`` produces the pure step the decode dry-run cells lower
-(one new token against a seq_len KV cache, greedy head). ``ContinuousBatcher``
-is the real serving loop used by the examples: a slot-based batcher whose
-admission queue is managed through the CWS (each admitted request is a CWSI
-task, so serving inherits workflow-aware ordering, provenance, and the
-runtime predictor for SLA estimates).
+``ContinuousBatcher`` is the serving loop used by the examples: a
+slot-based batcher whose admission queue is managed through the CWS (each
+admitted request is a CWSI task, so serving inherits workflow-aware
+ordering, provenance, and the runtime predictor for SLA estimates).
 """
 from __future__ import annotations
 
-import itertools
-import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from ..configs.base import ShapeConfig
 from ..models.model import Model
-from .sharding import decode_rules, input_axes, shardings_for_tree, train_rules
 
 
-def make_serve_step(model: Model, shape: ShapeConfig, mesh: Mesh,
-                    multi_pod: bool = False):
-    """Returns (serve_step, arg_shardings dict, input_specs)."""
-    long_ctx = shape.seq_len > 100_000
-    n_exp = model.cfg.moe.n_experts if model.cfg.moe else 0
-    use_ep = model.cfg.family == "moe" and n_exp >= 64
-    rules = decode_rules(multi_pod, long_ctx, model.cfg.family, n_exp)
-    specs = model.input_specs(shape)
-    ax = input_axes(model.cfg, "decode")
-    arg_sh = shardings_for_tree(specs, ax, rules, mesh)
-
-    p_specs = model.param_specs()
-    param_sh = shardings_for_tree(p_specs, model.param_axes(), rules, mesh)
-
-    from ..models.moe import ep_mode
-
-    def serve_step(params, cache, token, pos):
-        import contextlib
-        ctx = ep_mode() if use_ep else contextlib.nullcontext()
-        with ctx:
-            logits, new_cache = model.decode_step(params, cache, token, pos)
-        next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return next_token, new_cache
-
-    shardings = {"params": param_sh, **arg_sh}
-    return serve_step, shardings, {"params": p_specs, **specs}
-
-
-def make_prefill_step(model: Model, shape: ShapeConfig, mesh: Mesh,
-                      multi_pod: bool = False):
-    # prefill keeps train-style (non-EP) rules: measured — EP routing of
-    # 1M prefill tokens costs more collective than the 2-D weight sharding
-    rules = train_rules(multi_pod, model.cfg.family)
-    specs = model.input_specs(shape)
-    ax = input_axes(model.cfg, "prefill")
-    arg_sh = shardings_for_tree(specs, ax, rules, mesh)
-    p_specs = model.param_specs()
-    param_sh = shardings_for_tree(p_specs, model.param_axes(), rules, mesh)
-
-    def prefill_step(args):
-        params = args["params"]
-        inputs = {k: v for k, v in args.items() if k != "params"}
-        return _prefill_inner(params, inputs)
-
-    def _prefill_inner(params, inputs):
-        # enc-dec and SSM families "prefill" by running the forward pass
-        # (their serving state is built by the decode path / cross-KV fn);
-        # attention families build the KV cache.
-        if model.cfg.family in ("dense", "moe", "vlm"):
-            extra = {k: v for k, v in inputs.items() if k != "tokens"}
-            max_len = shape.seq_len
-            if model.cfg.family == "vlm" and model.cfg.vision is not None:
-                max_len += model.cfg.vision.n_patches
-            logits, cache = model.prefill(params, inputs["tokens"],
-                                          max_len, extra or None)
-            return jnp.argmax(logits, -1).astype(jnp.int32), cache
-        logits, aux = model.logits(params, {**inputs,
-                                            "labels": inputs.get("tokens")},
-                                   remat="none")
-        return jnp.argmax(logits[:, -1, :], -1).astype(jnp.int32), aux
-
-    return prefill_step, {"params": param_sh, **arg_sh}, \
-        {"params": p_specs, **specs}
-
-
-# ---------------------------------------------------------------------------
-# continuous batching (real serving loop for the examples)
-# ---------------------------------------------------------------------------
 @dataclass
 class Request:
     req_id: str

@@ -1,7 +1,7 @@
 """Logical-axis sharding rules (MaxText-style) with divisibility degradation.
 
-Every parameter/cache/activation dim carries a *logical* axis name; rules map
-logical axes → mesh axes per (shape-kind × mesh). Assignment degrades
+Every parameter and input dim carries a *logical* axis name; the rules map
+logical axes → mesh axes (``data``, ``model``). Assignment degrades
 gracefully: a mesh axis is only applied when the dim size is divisible by the
 mesh extent and the axis isn't already used by another dim of the same tensor
 — so one rule table serves all ten architectures (e.g. whisper's vocab 51865
@@ -9,11 +9,9 @@ is indivisible by 16 and silently replicates, gemma's 262144 shards).
 """
 from __future__ import annotations
 
-import math
 from typing import Any, Dict, Optional, Sequence, Tuple, Union
 
 import jax
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 AxisAssign = Union[None, str, Tuple[str, ...]]
@@ -28,22 +26,19 @@ def _as_tuple(a: AxisAssign) -> Tuple[str, ...]:
     return tuple(a)
 
 
-def base_rules(multi_pod: bool, family: str = "dense") -> Rules:
-    """Default parameter rules: TP over "model", DP/ZeRO over data axes.
+def base_rules(family: str = "dense") -> Rules:
+    """Default parameter rules: TP over "model", DP/ZeRO over "data".
 
     MoE expert weights dominate parameter bytes (mixtral: 264 of 280 GB) —
     model-axis TP alone leaves >17 GB/chip, so their hidden dim shards over
-    the data axes too (2-D weight sharding ≈ FSDP on the expert tensors;
+    the data axis too (2-D weight sharding ≈ FSDP on the expert tensors;
     XLA inserts the per-layer gathers)."""
-    ff: AxisAssign = "model"
-    if family == "moe":
-        ff = ("pod", "data", "model") if multi_pod else ("data", "model")
     return {
         "vocab": "model",
         "heads": "model",
         "kv_heads": "model",
-        "ff": ff,
-        "experts": None,            # EP variant applied in perf configs
+        "ff": ("data", "model") if family == "moe" else "model",
+        "experts": None,
         "ssm_inner": "model",
         "embed": None,
         "layers": None,
@@ -52,38 +47,8 @@ def base_rules(multi_pod: bool, family: str = "dense") -> Rules:
     }
 
 
-def batch_axes(multi_pod: bool) -> Tuple[str, ...]:
-    return ("pod", "data") if multi_pod else ("data",)
-
-
-def decode_rules(multi_pod: bool, long_context: bool,
-                 family: str = "dense", n_experts: int = 0) -> Rules:
-    """Cache/activation rules for serving cells.
-
-    MoE *decode* uses expert parallelism when the expert count is large
-    (measured: qwen3's 128 experts → collective 3.5→0.7 ms/step and weights
-    fit without cross-axis gathers; mixtral's 8 experts measured WORSE under
-    EP — pod-spanning expert ownership turns the residual ff traffic into
-    DCN — so small-E archs keep the 2-D ff sharding)."""
-    r = base_rules(multi_pod, family)
-    if family == "moe" and n_experts >= 64:
-        r["experts"] = ("pod", "data") if multi_pod else ("data",)
-    r.update({
-        "batch": batch_axes(multi_pod),
-        # long-context (batch=1): spread KV slots over everything;
-        # normal decode: batch over data axes, slots over model.
-        "kv_seq": (("pod", "data", "model") if multi_pod else ("data", "model"))
-        if long_context else "model",
-        "kv_heads_cache": None if long_context else None,
-        "ssm_heads": "model",
-    })
-    return r
-
-
-def train_rules(multi_pod: bool, family: str = "dense") -> Rules:
-    r = base_rules(multi_pod, family)
-    r.update({"batch": batch_axes(multi_pod)})
-    return r
+def train_rules(family: str = "dense") -> Rules:
+    return {**base_rules(family), "batch": "data"}
 
 
 def spec_for(shape: Sequence[int], axes: Sequence[Optional[str]],
@@ -123,49 +88,12 @@ def shardings_for_tree(shapes_tree: Any, axes_tree: Any, rules: Rules,
     return jax.tree.unflatten(treedef, out)
 
 
-# ---------------------------------------------------------------------------
-# cache logical axes per family (parallel to models.*.cache_shapes)
-# ---------------------------------------------------------------------------
-def cache_axes(cfg) -> Dict[str, Any]:
-    if cfg.family in ("dense", "moe", "vlm"):
-        kinds = {}
-        from ..models.transformer import layer_pattern
-        pat = layer_pattern(cfg)
-        for kind in set(pat):
-            kinds[kind] = {
-                "k": (None, None, "batch", "kv_seq", "kv_heads_cache", None),
-                "v": (None, None, "batch", "kv_seq", "kv_heads_cache", None),
-            }
-        return kinds
-    if cfg.family == "ssm":
-        return {"conv": (None, "batch", None, "ssm_inner"),
-                "ssm": (None, "batch", "ssm_heads", None, None)}
-    if cfg.family == "hybrid":
-        axes = {"conv": (None, None, "batch", None, "ssm_inner"),
-                "ssm": (None, None, "batch", "ssm_heads", None, None)}
-        if cfg.hybrid is not None and cfg.hybrid.shared_attn:
-            axes["attn_k"] = (None, "batch", "kv_seq", "kv_heads_cache", None)
-            axes["attn_v"] = (None, "batch", "kv_seq", "kv_heads_cache", None)
-        return axes
+def input_axes(cfg) -> Dict[str, Any]:
+    """Logical axes for the ``Model.input_specs()`` tree."""
+    ax: Dict[str, Any] = {"tokens": ("batch", None),
+                          "labels": ("batch", None)}
+    if cfg.family == "vlm":
+        ax["patches"] = ("batch", None, None)
     if cfg.family == "audio":
-        a = (None, "batch", "kv_seq", "kv_heads_cache", None)
-        return {"self_k": a, "self_v": a,
-                "cross_k": (None, "batch", None, "kv_heads_cache", None),
-                "cross_v": (None, "batch", None, "kv_heads_cache", None)}
-    raise ValueError(cfg.family)
-
-
-def input_axes(cfg, kind: str) -> Dict[str, Any]:
-    """Logical axes for the input_specs() trees."""
-    if kind in ("train", "prefill"):
-        ax: Dict[str, Any] = {"tokens": ("batch", None)}
-        if kind == "train":
-            ax["labels"] = ("batch", None)
-        if cfg.family == "vlm":
-            ax["patches"] = ("batch", None, None)
-        if cfg.family == "audio":
-            ax["frames"] = ("batch", None, None)
-        return ax
-    return {"cache": cache_axes(cfg),
-            "token": ("batch",),
-            "pos": ()}
+        ax["frames"] = ("batch", None, None)
+    return ax

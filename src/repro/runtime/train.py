@@ -1,51 +1,40 @@
-"""Distributed train-step factory.
+"""Train-step factory.
 
 Composes: microbatched gradient accumulation (``lax.scan``), remat (inside
-the model's layer scan), AdamW with fp32 master weights, ZeRO-1 optimizer-
-state sharding (extra data-axis assignment per state tensor), global-norm
-clipping, and optional int8 error-feedback gradient compression state for
-the cross-pod hop.
+the model's layer scan), AdamW with fp32 master weights and bf16 moments,
+ZeRO-1 optimizer-state sharding (an extra data-axis assignment per state
+tensor), ZeRO-2 gradient accumulation in that sharding, and global-norm
+clipping.
 
-The returned artifacts are *specs + a pure function*, so the launcher can
-``jax.jit(...).lower(...).compile()`` them against ShapeDtypeStructs (dry-
-run) or run them for real (examples/tests) without code changes.
+The returned artifacts are *specs + a pure function*, so a caller can
+``jax.jit(...).lower(...).compile()`` them against ShapeDtypeStructs (a
+described chip) or run them for real without code changes.
 """
 from __future__ import annotations
 
-import math
-from dataclasses import dataclass
-from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from ..configs.base import ModelConfig, RunConfig, ShapeConfig, TrainConfig
+from ..configs.base import ShapeConfig, TrainConfig
 from ..models.model import Model
 from ..optim.adamw import AdamW, AdamWState, warmup_cosine
 from .sharding import (
     Rules,
-    batch_axes,
     input_axes,
     shardings_for_tree,
     spec_for,
     train_rules,
 )
 
+MOMENT_DTYPE = "bfloat16"   # AdamW's moments; the master weights stay f32
+
 
 # ---------------------------------------------------------------------------
-def dp_size(mesh: Mesh, multi_pod: bool) -> int:
-    n = 1
-    for ax in batch_axes(multi_pod):
-        n *= mesh.shape.get(ax, 1)
-    return n
-
-
-def n_microbatches(shape: ShapeConfig, mesh: Mesh, tcfg: TrainConfig,
-                   multi_pod: bool) -> int:
-    per_dev = shape.global_batch // dp_size(mesh, multi_pod)
+def n_microbatches(shape: ShapeConfig, mesh: Mesh, tcfg: TrainConfig) -> int:
+    per_dev = shape.global_batch // mesh.shape.get("data", 1)
     return max(1, per_dev // max(tcfg.microbatch_per_device, 1))
 
 
@@ -73,8 +62,7 @@ def attention_path(model: Model, mesh: Mesh, shape: ShapeConfig) -> str:
 
 
 def make_train_step(model: Model, tcfg: TrainConfig, shape: ShapeConfig,
-                    mesh: Mesh, multi_pod: bool = False,
-                    total_steps: int = 10_000):
+                    mesh: Mesh, total_steps: int = 10_000):
     """Returns (train_step, state_shardings, batch_shardings, state_specs).
 
     Attention is chosen here, by ``attention_path``; the SSD kernel runs
@@ -87,20 +75,18 @@ def make_train_step(model: Model, tcfg: TrainConfig, shape: ShapeConfig,
                   or bool(model.use_pallas),
                   interpret=mesh.devices.flat[0].platform != "tpu")
     opt = _optimizer(tcfg, total_steps)
-    n_micro = n_microbatches(shape, mesh, tcfg, multi_pod)
-    rules = train_rules(multi_pod, model.cfg.family)
+    n_micro = n_microbatches(shape, mesh, tcfg)
+    rules = train_rules(model.cfg.family)
 
     # ---- state specs ----
     p_specs = model.param_specs()
     p_axes = model.param_axes()
     param_sh = shardings_for_tree(p_specs, p_axes, rules, mesh)
-    opt_sh = _zero1_shardings(p_specs, p_axes, rules, mesh,
-                              enable=tcfg.zero1)
-    mdt = jnp.bfloat16 if tcfg.opt_dtype == "bfloat16" else jnp.float32
+    opt_sh = _zero1_shardings(p_specs, p_axes, rules, mesh)
     f32 = lambda t: jax.tree.map(  # noqa: E731
         lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32), t)
     fm = lambda t: jax.tree.map(  # noqa: E731
-        lambda s: jax.ShapeDtypeStruct(s.shape, mdt), t)
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.dtype(MOMENT_DTYPE)), t)
     state_specs = {
         "params": p_specs,
         "opt": AdamWState(jax.ShapeDtypeStruct((), jnp.int32),
@@ -115,7 +101,7 @@ def make_train_step(model: Model, tcfg: TrainConfig, shape: ShapeConfig,
     }
 
     # ---- batch specs ----
-    in_ax = input_axes(model.cfg, "train")
+    in_ax = input_axes(model.cfg)
     batch_specs = model.input_specs(shape)
     batch_sh = shardings_for_tree(batch_specs, in_ax, rules, mesh)
 
@@ -124,7 +110,7 @@ def make_train_step(model: Model, tcfg: TrainConfig, shape: ShapeConfig,
     # only its update shard and the backward emits reduce-scatters. An
     # unconstrained scan carry would replicate them (observed: +30 GB/device
     # on qwen2-7b; mixtral's f32 grads alone are 4.9 GB/device unsharded).
-    grad_sh = opt_sh if tcfg.zero2 else param_sh
+    grad_sh = opt_sh
 
     # ---- the step ----
     def train_step(state: Dict[str, Any], batch: Dict[str, jax.Array]):
@@ -136,8 +122,7 @@ def make_train_step(model: Model, tcfg: TrainConfig, shape: ShapeConfig,
             # groups of the major dim), silently replicating each micro
             # step's batch on every device (observed 16x activation blow-up).
             # Keep n_micro minor, swap, and pin the sharding explicitly.
-            bax = batch_axes(multi_pod)
-            bspec = tuple(a for a in bax if a in mesh.shape)
+            bspec = ("data",) if "data" in mesh.shape else ()
 
             def split(x):
                 x = x.reshape(x.shape[0] // n_micro, n_micro,
@@ -183,8 +168,8 @@ def make_train_step(model: Model, tcfg: TrainConfig, shape: ShapeConfig,
     return train_step, state_sh, batch_sh, state_specs
 
 
-def _zero1_shardings(p_specs: Any, p_axes: Any, rules: Rules, mesh: Mesh,
-                     enable: bool = True) -> Any:
+def _zero1_shardings(p_specs: Any, p_axes: Any, rules: Rules,
+                     mesh: Mesh) -> Any:
     """Optimizer-state shardings: the param spec + one extra data-axis
     assignment on the first unsharded divisible dim (ZeRO-1)."""
     is_sds = lambda x: isinstance(x, jax.ShapeDtypeStruct)  # noqa: E731
@@ -195,7 +180,7 @@ def _zero1_shardings(p_specs: Any, p_axes: Any, rules: Rules, mesh: Mesh,
     for s, ax in zip(flat_s, flat_a):
         spec = list(spec_for(s.shape, ax, rules, mesh))
         spec += [None] * (len(s.shape) - len(spec))
-        if enable and data_n > 1:
+        if data_n > 1:
             used = {a for e in spec if e
                     for a in (e if isinstance(e, tuple) else (e,))}
             if "data" not in used:
@@ -211,7 +196,7 @@ def _optimizer(tcfg: TrainConfig, total_steps: int) -> AdamW:
     return AdamW(lr=warmup_cosine(tcfg.learning_rate, tcfg.warmup_steps,
                                   total_steps),
                  weight_decay=tcfg.weight_decay, grad_clip=tcfg.grad_clip,
-                 mom_dtype=tcfg.opt_dtype)
+                 mom_dtype=MOMENT_DTYPE)
 
 
 def init_state(model: Model, tcfg: TrainConfig, rng: jax.Array,

@@ -55,7 +55,7 @@ def test_smoke_train_step_reduces_loss(arch):
     cfg = SMOKE_ARCHS[arch]
     model = build_model(cfg)
     mesh = make_host_mesh()
-    shape = ShapeConfig("tiny", S, B, "train")
+    shape = ShapeConfig("tiny", S, B)
     tcfg = TrainConfig(learning_rate=5e-3, warmup_steps=2,
                        microbatch_per_device=B)
     step, state_sh, batch_sh, _ = make_train_step(model, tcfg, shape, mesh)
@@ -82,7 +82,7 @@ def test_cpu_mesh_train_step_keeps_xla_attention(arch):
     cfg = SMOKE_ARCHS[arch]
     model = build_model(cfg)
     mesh = make_host_mesh()
-    shape = ShapeConfig("tiny", S, B, "train")
+    shape = ShapeConfig("tiny", S, B)
     assert attention_path(model, mesh, shape) == "xla"
     step, _, _, specs = make_train_step(
         model, TrainConfig(microbatch_per_device=B), shape, mesh)

@@ -1,5 +1,5 @@
-"""Prediction plugins (paper §5): Lotaru-style runtime prediction, feedback
-memory prediction, and the roofline prior."""
+"""Prediction plugins (paper §5): Lotaru-style runtime prediction and
+feedback memory prediction."""
 import math
 
 import numpy as np
@@ -16,8 +16,6 @@ from repro.core.predict import (
     FeedbackMemoryPredictor,
     LotaruPredictor,
     NodeProfile,
-    RooflinePrior,
-    RooflineTerms,
 )
 from repro.core.provenance import ProvenanceStore, TaskTrace
 
@@ -96,18 +94,6 @@ def test_memory_predictor_retry_doubles():
     a1 = pred.allocate("x", GiB, 2 * GiB, attempt=1)
     a2 = pred.allocate("x", GiB, 2 * GiB, attempt=2)
     assert a1 == 2 * a0 and a2 == 4 * a0
-
-
-def test_roofline_prior_seeds_lotaru():
-    prior = RooflinePrior()
-    terms = RooflineTerms(compute_s=0.10, memory_s=0.04, collective_s=0.02)
-    prior.register("train_chunk", terms, steps_per_task=10)
-    assert prior.predict("train_chunk") == pytest.approx(1.1)
-    assert terms.dominant == "compute"
-    lot = LotaruPredictor()
-    prior.seed(lot)
-    mu, _ = lot.predict("train_chunk", 1 << 30)
-    assert 0.8 < mu < 1.5                      # ≈ step_s × steps
 
 
 @settings(max_examples=20, deadline=None)

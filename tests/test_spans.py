@@ -169,8 +169,8 @@ def test_the_ssd_scan_is_scoped_forward_and_backward():
     model = build_model(get_config("mamba2-370m", smoke=True))
     step, state_sh, batch_sh, specs = make_train_step(
         model, TrainConfig(microbatch_per_device=2),
-        ShapeConfig("t", 64, 4, "train"), make_host_mesh(), total_steps=4)
+        ShapeConfig("t", 64, 4), make_host_mesh(), total_steps=4)
     hlo = jax.jit(step).lower(
-        specs, model.input_specs(ShapeConfig("t", 64, 4, "train"))
+        specs, model.input_specs(ShapeConfig("t", 64, 4))
     ).compile().as_text()
     assert _scoped(hlo)["ssd"] == {"forward", "backward"}

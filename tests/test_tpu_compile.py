@@ -100,16 +100,16 @@ def test_attention_path_follows_the_mesh(topo):
 
     model = build_model(get_config("qwen1.5-0.5b"))
     one = make_host_mesh(topo.devices[:1])
-    shape = ShapeConfig("s", 1024, 8, "train")
+    shape = ShapeConfig("s", 1024, 8)
     assert attention_path(model, one, shape) == "pallas"
     assert attention_path(model, make_host_mesh(topo.devices), shape) == "xla"
-    assert attention_path(model, one, ShapeConfig("s", 1000, 8, "train")) \
+    assert attention_path(model, one, ShapeConfig("s", 1000, 8)) \
         == "xla"
     forced = build_model(get_config("qwen1.5-0.5b"), use_pallas=False)
     assert attention_path(forced, one, shape) == "xla"
     # 32,768 tokens + 576 patches is off the 128 grid
     vlm = build_model(get_config("phi-3-vision-4.2b"))
-    assert attention_path(vlm, one, ShapeConfig("s", 32768, 1, "train")) \
+    assert attention_path(vlm, one, ShapeConfig("s", 32768, 1)) \
         == "xla"
 
 
@@ -124,7 +124,7 @@ def test_full_width_train_step_fits_one_chip(topo):
     from repro.runtime.train import make_train_step
 
     model = build_model(get_config("qwen1.5-0.5b"))
-    shape = ShapeConfig("smoke", 1024, 8, "train")
+    shape = ShapeConfig("smoke", 1024, 8)
     tcfg = TrainConfig(microbatch_per_device=4)
     step, state_sh, batch_sh, state_specs = make_train_step(
         model, tcfg, shape, make_host_mesh(topo.devices[:1]))
